@@ -60,4 +60,6 @@ def main(quick: bool = False):
 
 
 if __name__ == "__main__":
+    from repro.utils.compile_cache import enable_compile_cache
+    enable_compile_cache()
     main()

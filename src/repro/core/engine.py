@@ -42,6 +42,7 @@ SpecInfer multi-round rejection (``core.sampling``), and per-request
 """
 from __future__ import annotations
 
+import contextlib
 import functools
 from dataclasses import dataclass, field, replace as dc_replace
 from typing import Any, Dict, List, Optional, Sequence, Tuple
@@ -398,6 +399,18 @@ class SpecPVEngine:
     def _build_jits(self):
         cfg, spec, dcfg, tree = self.cfg, self.spec, self.dcfg, self.tree
 
+        def keep_pools(cache, dcache):
+            """Pin the shared pools of a prefill step's output to their
+            placement (``_pool_sharding``), as ``_keep_placement`` does
+            for the fused step."""
+            if self.mesh is None or not self.paged:
+                return cache, dcache
+            wsc = jax.lax.with_sharding_constraint
+            return (dict(cache, **{n: wsc(cache[n], self._pool_sharding(
+                        cache[n], 1)) for n in kvc.PAGED_POOL_KEYS}),
+                    dict(dcache, **{n: wsc(dcache[n], self._pool_sharding(
+                        dcache[n], 0)) for n in kvc.DRAFT_POOL_KEYS}))
+
         # cache/dcache die at the call site (the chunk loop rebinds), so
         # donate them — for paged engines this keeps the shared pool from
         # being copied once per prefill chunk
@@ -416,7 +429,7 @@ class SpecPVEngine:
             # the full fused chunk is returned (not just the last column)
             # so the host loop can harvest block-boundary features for
             # prefix-cache registration; prev_feat is fused[:, -1]
-            return (cache, dcache, logits, fused)
+            return (*keep_pools(cache, dcache), logits, fused)
 
         self._prefill_chunk = _prefill_chunk
 
@@ -457,7 +470,9 @@ class SpecPVEngine:
             last = jnp.clip(t_valid - 1, 0)
             feat_last = jnp.take_along_axis(fused, last[:, None, None],
                                             axis=1)[:, 0]  # [K, 3d]
-            if rows:
+            if not rows:
+                cache_out, dcache_out = keep_pools(cache_out, dcache_out)
+            else:
                 def srow(a, i, ax):
                     return jax.lax.slice_in_dim(a, i, i + 1, axis=ax)
                 cache_out = [
@@ -787,6 +802,13 @@ class SpecPVEngine:
             # (merge_state_rows assumes the attention cache layout)
             self._step_state = jax.jit(_step_state)
 
+    def _on_mesh(self):
+        """The engine's mesh as the ambient mesh while a model step
+        traces and runs: XLA cannot partition a Pallas kernel, so the
+        kernel wrappers (``kernels.ops``) shard_map themselves over it."""
+        return (jax.set_mesh(self.mesh) if self.mesh is not None
+                else contextlib.nullcontext())
+
     def _fused_fn(self, has_full: bool, has_partial: bool,
                   has_refresh: bool, has_sampled: bool = False,
                   has_chain: bool = False):
@@ -807,7 +829,8 @@ class SpecPVEngine:
             def run(params, dparams, st, active, modes, is_chain):
                 st2, out = impl(params, dparams, st, active, modes,
                                 is_chain)
-                return merge_state_rows(active, st2, st), out
+                return (self._keep_placement(merge_state_rows(active, st2,
+                                                              st)), out)
 
             fn = jax.jit(run, donate_argnums=(2,))
             self._fused_jits[key] = fn
@@ -914,9 +937,10 @@ class SpecPVEngine:
         while off < s0:
             end = min(s0, (off // chunk + 1) * chunk)
             toks = jnp.asarray(prompt[:, off: end])
-            cache, dcache, logits_last, fused = self._prefill_chunk(
-                self.params, self.dparams, cache, dcache, toks, prev_feat,
-                extra)
+            with self._on_mesh():
+                cache, dcache, logits_last, fused = self._prefill_chunk(
+                    self.params, self.dparams, cache, dcache, toks,
+                    prev_feat, extra)
             prev_feat = fused[:, -1]
             off = end
         return self._boot_state(cache, dcache, logits_last, prev_feat, s0)
@@ -1035,7 +1059,13 @@ class SpecPVEngine:
             self._forked_slots.clear()
             if self._tier is not None:
                 self._tier.reset()
-        return self.shard_state(self._neutral_state(self.batch))
+        make = functools.partial(self._neutral_state, self.batch)
+        if self.mesh is None:
+            return make()
+        # created directly in its shardings, so no device ever holds the
+        # whole state; every later step keeps them (``_keep_placement``)
+        return jax.jit(make, out_shardings=self.state_shardings(
+            jax.eval_shape(make)))()
 
     def _clear_prefix(self) -> None:
         if self._prefix is not None:
@@ -1183,8 +1213,10 @@ class SpecPVEngine:
         over ``data``; the paged pools shard their *page* axis over
         ``data`` (the allocator's contiguous per-shard ranges line up
         with the device split, so each host physically holds exactly
-        the pages its slots may reference); contiguous full caches
-        shard batch over ``data`` and sequence over ``model``.  Jitting
+        the pages its slots may reference) and, like the partial cache,
+        their kv-head axis over ``model`` (see ``_pool_sharding``);
+        contiguous full caches shard batch over ``data`` and sequence
+        over ``model``.  Jitting
         the fused step with these as input shardings is what makes the
         one dispatch per tick an SPMD dispatch — one launch *per host*,
         each covering only its slot range."""
@@ -1201,13 +1233,7 @@ class SpecPVEngine:
         def div(n, shards):
             return shards > 1 and n % shards == 0
 
-        def pool_spec(v, page_axis):
-            pax = ("data" if bax and div(v.shape[page_axis],
-                                         self.data_shards) else None)
-            spec = [None] * v.ndim
-            spec[page_axis] = pax
-            return ns(*spec)
-
+        pool_spec = self._pool_sharding
         cache_sh = {}
         for k2, v in st.cache.items():
             if k2 in kvc.PAGED_POOL_KEYS and self.paged:
@@ -1240,9 +1266,10 @@ class SpecPVEngine:
             return ns(bax, *([None] * (a.ndim - 1)))
 
         def pkv_spec(a):                                # [L, B, Hk, ...]
-            if a.ndim < 2:                              # no-attn placeholder
+            if a.ndim < 3:                              # no-attn placeholder
                 return ns(*([None] * a.ndim))
-            return ns(None, bax, *([None] * (a.ndim - 2)))
+            hax = max_ if div(a.shape[2], self.model_shards) else None
+            return ns(None, bax, hax, *([None] * (a.ndim - 3)))
 
         return EngineState(
             cache=cache_sh, dcache=dcache_sh,
@@ -1256,12 +1283,26 @@ class SpecPVEngine:
             keys=rowlike(st.keys), temps=rowlike(st.temps),
             pkv_blocks=rowlike(st.pkv_blocks))
 
-    def shard_state(self, st: EngineState) -> EngineState:
-        """Place `st` onto the mesh per ``state_shardings`` (identity
-        when unsharded).  Called once at serving boot; every later step
-        preserves the placement through GSPMD propagation."""
+    def _pool_sharding(self, v, page_axis: int):
+        """NamedSharding of a paged pool array (trunk ``[L, NP, ...]``
+        or draft ``[NP, ...]``, kv heads second to last): pages over
+        ``data`` (per-shard page ranges), kv heads over ``model`` —
+        tensor-parallel attention reads only its own heads, which is
+        how the kernels run under a mesh (``kernels.ops._per_shard``)."""
+        from jax.sharding import NamedSharding, PartitionSpec as P
+        spec = [None] * v.ndim
+        if self.data_shards > 1 and v.shape[page_axis] % self.data_shards == 0:
+            spec[page_axis] = "data"
+        if self.model_shards > 1 and v.shape[-2] % self.model_shards == 0:
+            spec[-2] = "model"
+        return NamedSharding(self.mesh, P(*spec))
+
+    def _keep_placement(self, st: EngineState) -> EngineState:
+        """Inside a jitted step: pin the output state to the boot
+        placement (``state_shardings``), so the next step's inputs keep
+        it — no resharding, no recompile, and donated buffers alias."""
         sh = self.state_shardings(st)
-        return st if sh is None else jax.device_put(st, sh)
+        return st if sh is None else jax.lax.with_sharding_constraint(st, sh)
 
     def page_capacity(self) -> int:
         return self._page_alloc.capacity if self.paged else 1 << 30
@@ -1803,9 +1844,10 @@ class SpecPVEngine:
             sub_dcache.update(cur.row_dcache)
         else:
             sub_cache, sub_dcache = cur.row_cache, cur.row_dcache
-        cache, dcache, logits_last, fused = self._prefill_chunk(
-            self.params, self.dparams, sub_cache, sub_dcache, toks,
-            cur.prev_feat, cur.extra)
+        with self._on_mesh():
+            cache, dcache, logits_last, fused = self._prefill_chunk(
+                self.params, self.dparams, sub_cache, sub_dcache, toks,
+                cur.prev_feat, cur.extra)
         self.prefill_dispatches += 1
 
         cur.prev_feat = fused[:, -1]
@@ -1960,9 +2002,11 @@ class SpecPVEngine:
         else:
             sub_cache = [c.row_cache for c in cursors]
             sub_dcache = [c.row_dcache for c in cursors]
-        cache, dcache, logits, fused, feat_last = self._prefill_chunk_fused(
-            self.params, self.dparams, sub_cache, sub_dcache,
-            jnp.asarray(toks), t_valid, prev_feat)
+        with self._on_mesh():
+            cache, dcache, logits, fused, feat_last = \
+                self._prefill_chunk_fused(
+                    self.params, self.dparams, sub_cache, sub_dcache,
+                    jnp.asarray(toks), t_valid, prev_feat)
         self.prefill_dispatches += 1
 
         total = 0
@@ -2254,9 +2298,11 @@ class SpecPVEngine:
             st = self._tier_promote_rows(st, rows, modes)
         fn = self._fused_fn(has_full, has_partial, has_refresh,
                             has_sampled, has_chain)
-        st, (toks, counts, acc) = fn(self.params, self.dparams, st,
-                                     jnp.asarray(rows), jnp.asarray(modes),
-                                     jnp.asarray(self._slot_chain))
+        with self._on_mesh():
+            st, (toks, counts, acc) = fn(self.params, self.dparams, st,
+                                         jnp.asarray(rows),
+                                         jnp.asarray(modes),
+                                         jnp.asarray(self._slot_chain))
         self.dispatches += 1
         self._pkv_active_rows |= rows & (modes == MODE_REFRESH)
         if self.zero_copy and has_refresh:

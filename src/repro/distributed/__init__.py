@@ -1,6 +1,5 @@
 from repro.distributed.sharding import (param_shardings, cache_shardings,
                                         batch_spec, ShardingRules)
-from repro.distributed.compat import shard_map
 from repro.distributed.cp_retrieval import cp_partial_verify_attention
 from repro.distributed.cp_verify import (cp_full_verify_attention,
                                          psum_softmax_merge,
@@ -9,7 +8,7 @@ from repro.distributed.cp_verify import (cp_full_verify_attention,
                                          verify_traffic_report)
 
 __all__ = ["param_shardings", "cache_shardings", "batch_spec",
-           "ShardingRules", "shard_map", "cp_partial_verify_attention",
+           "ShardingRules", "cp_partial_verify_attention",
            "cp_full_verify_attention", "psum_softmax_merge",
            "merged_partials_bytes", "gathered_blocks_bytes",
            "verify_traffic_report"]

@@ -26,7 +26,6 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from repro.configs.base import SpecPVConfig
-from repro.distributed.compat import shard_map
 from repro.distributed.cp_verify import psum_softmax_merge
 from repro.kernels import ref as kref
 
@@ -84,9 +83,9 @@ def cp_partial_verify_attention(mesh, axis: str, spec: SpecPVConfig,
                                         axis)
 
     seq_spec = P(None, axis, None, None)
-    fn = shard_map(body, mesh=mesh,
-                   in_specs=(P(), seq_spec, seq_spec, seq_spec, seq_spec,
-                             P()),
-                   out_specs=P(),
-                   check_rep=False)
+    fn = jax.shard_map(body, mesh=mesh,
+                       in_specs=(P(), seq_spec, seq_spec, seq_spec, seq_spec,
+                                 P()),
+                       out_specs=P(),
+                       check_vma=False)
     return fn(q, k_cache, v_cache, kmax, kmin, length)

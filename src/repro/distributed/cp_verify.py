@@ -28,8 +28,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.distributed.compat import shard_map
-
 
 # ---------------------------------------------------------------------------
 # the softmax-partials merge (shared with cp_retrieval)
@@ -99,9 +97,9 @@ def cp_full_verify_attention(mesh, axis: str, q, k_cache, v_cache, length):
         return out.transpose(0, 2, 1, 3).astype(q_.dtype)    # [B,T,H,Dh]
 
     seq_spec = P(None, axis, None, None)
-    fn = shard_map(body, mesh=mesh,
-                   in_specs=(P(), seq_spec, seq_spec, P()),
-                   out_specs=P(), check_rep=False)
+    fn = jax.shard_map(body, mesh=mesh,
+                       in_specs=(P(), seq_spec, seq_spec, P()),
+                       out_specs=P(), check_vma=False)
     return fn(q, k_cache, v_cache, length)
 
 

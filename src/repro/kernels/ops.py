@@ -13,14 +13,43 @@ import jax
 import jax.numpy as jnp
 
 from repro.kernels.block_summary import block_summary_pallas
-from repro.kernels.prefill_attention import paged_prefill_attention_pallas
 from repro.kernels.retrieval_score import retrieval_score_pallas
-from repro.kernels.sparse_attention import sparse_verify_attention_pallas
+from repro.kernels.sparse_attention import block_attention_pallas
 from repro.kernels import ref
+from repro.models import common as cm
 
 
 def _interpret() -> bool:
     return jax.default_backend() != "tpu"
+
+
+def _per_shard(fn, args, in_dims, out_dims, heads: int):
+    """``fn(*args)``, run per shard of the ambient mesh (``jax.set_mesh``).
+
+    XLA cannot partition a Mosaic kernel, so under a mesh the call goes
+    through ``shard_map``: head-parallel over ``model`` when that axis
+    divides the ``heads`` kv heads (``in_dims`` gives each operand's
+    head axis, None = replicated; ``out_dims`` each output's, in the
+    outputs' structure), otherwise every device computes the whole call.
+    Without a mesh it is ``fn(*args)``."""
+    from jax.sharding import PartitionSpec as P
+    mesh = cm.current_mesh()
+    if mesh is None:
+        return fn(*args)
+    ax = ("model" if "model" in mesh.axis_names
+          and heads % mesh.shape["model"] == 0 else None)
+
+    def spec(ndim, d):
+        dims = [None] * ndim
+        if d is not None:
+            dims[d] = ax
+        return P(*dims)
+
+    out_specs = jax.tree.map(lambda o, d: spec(o.ndim, d),
+                             jax.eval_shape(fn, *args), out_dims)
+    in_specs = tuple(spec(a.ndim, d) for a, d in zip(args, in_dims))
+    return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)(*args)
 
 
 @functools.partial(jax.jit, static_argnames=("block_size", "use_pallas"))
@@ -41,7 +70,29 @@ def retrieval_scores(q, kmax, kmin, q_weight, use_pallas: bool = True):
     q_weight: [B, T].  Returns [B, Hk, NB] fp32."""
     fn = (functools.partial(retrieval_score_pallas, interpret=_interpret())
           if use_pallas else ref.retrieval_score_ref)
-    return jax.vmap(fn)(q, kmax, kmin, q_weight)
+    return _per_shard(jax.vmap(fn), (q, kmax, kmin, q_weight),
+                    (2, 2, 2, None), 1, kmax.shape[2])
+
+
+def _block_attention(q, k_blocks, v_blocks, block_idx, block_valid_len,
+                     q_pos=None, *, use_pallas: bool):
+    """One row: the block-attention kernel, or its ``ref`` oracle behind
+    the kernel's signature (``sparse_attention.block_attention_pallas``)."""
+    if use_pallas:
+        return block_attention_pallas(q, k_blocks, v_blocks, block_idx,
+                                      block_valid_len, q_pos,
+                                      interpret=_interpret())
+    nb, bs, hk, dh = k_blocks.shape
+    kf = k_blocks.reshape(nb * bs, hk, dh)
+    vf = v_blocks.reshape(nb * bs, hk, dh)
+    shape = (hk, block_idx.shape[1])
+    idx = jnp.broadcast_to(block_idx, shape)
+    vlen = jnp.broadcast_to(block_valid_len, shape)
+    if q_pos is None:
+        return ref.sparse_verify_attention_ref(q, kf, vf, idx, vlen,
+                                               block_size=bs)
+    return ref.paged_prefill_attention_ref(q, kf, vf, idx, vlen, q_pos[:1],
+                                           block_size=bs)
 
 
 @functools.partial(jax.jit, static_argnames=("block_size", "use_pallas"))
@@ -51,12 +102,12 @@ def sparse_verify_attention(q, k_cache, v_cache, block_idx, block_valid_len,
 
     q: [B, T, H, Dh]; caches: [B, S, Hk, Dh]; idx/vlen: [B, Hk, NSel].
     Returns (m [B, H, T], l [B, H, T], acc [B, H, T, Dh])."""
-    fn = (functools.partial(sparse_verify_attention_pallas,
-                            block_size=block_size, interpret=_interpret())
-          if use_pallas else
-          functools.partial(ref.sparse_verify_attention_ref,
-                            block_size=block_size))
-    return jax.vmap(fn)(q, k_cache, v_cache, block_idx, block_valid_len)
+    b, s, hk, dh = k_cache.shape
+    nb = s // block_size
+    kb = k_cache[:, : nb * block_size].reshape(b, nb, block_size, hk, dh)
+    vb = v_cache[:, : nb * block_size].reshape(b, nb, block_size, hk, dh)
+    fn = functools.partial(_block_attention, use_pallas=use_pallas)
+    return jax.vmap(fn)(q, kb, vb, block_idx, block_valid_len)
 
 
 @functools.partial(jax.jit, static_argnames=("use_pallas",))
@@ -65,7 +116,7 @@ def paged_verify_attention(q, pool_k, pool_v, page_table, length,
     """Paged dense/refresh verification attention over the shared block
     pool (softmax partials).
 
-    Reuses the block-sparse kernel's scalar-prefetch index_map: the
+    Reuses the block-attention kernel's scalar-prefetch index_map: the
     slot's page table IS the block-id table, so the pipeline streams
     exactly the resident pages HBM->VMEM — the contiguous [B, S, ...]
     view is never materialised.  Per-block valid lengths are derived
@@ -86,22 +137,18 @@ def paged_verify_attention(q, pool_k, pool_v, page_table, length,
     Returns (m [B, H, T], l [B, H, T], acc [B, H, T, Dh]) fp32 —
     combinable with the tree self-segment via
     ``models.common.combine_attn_parts``."""
-    np_, bs, hk, dh = pool_k.shape
-    b, nb = page_table.shape
-    k_flat = pool_k.reshape(np_ * bs, hk, dh)
-    v_flat = pool_v.reshape(np_ * bs, hk, dh)
+    bs = pool_k.shape[1]
+    nb = page_table.shape[1]
     vlen = jnp.clip(length[:, None] - jnp.arange(nb)[None] * bs, 0, bs)
     # ragged routing: empty blocks stream the null page (their valid
     # length is 0, so the masked tile contributes nothing either way)
     routed = jnp.where(vlen > 0, page_table, 0)
-    idx = jnp.broadcast_to(routed[:, None], (b, hk, nb)).astype(jnp.int32)
-    vlen_h = jnp.broadcast_to(vlen[:, None], (b, hk, nb)).astype(jnp.int32)
-    fn = (functools.partial(sparse_verify_attention_pallas, block_size=bs,
-                            interpret=_interpret())
-          if use_pallas else
-          functools.partial(ref.sparse_verify_attention_ref, block_size=bs))
-    return jax.vmap(fn, in_axes=(0, None, None, 0, 0))(q, k_flat, v_flat,
-                                                       idx, vlen_h)
+    fn = jax.vmap(functools.partial(_block_attention, use_pallas=use_pallas),
+                  in_axes=(0, None, None, 0, 0))
+    return _per_shard(fn, (q, pool_k, pool_v,
+                         routed[:, None].astype(jnp.int32),
+                         vlen[:, None].astype(jnp.int32)),
+                    (2, 2, 2, None, None), (1, 1, 1), pool_k.shape[2])
 
 
 @functools.partial(jax.jit, static_argnames=("use_pallas",))
@@ -127,16 +174,11 @@ def routed_partial_attention(q, pool_k, pool_v, block_idx, block_valid_len,
     Returns (m [B, H, T], l [B, H, T], acc [B, H, T, Dh]) fp32 —
     combinable with the tail-buffer and tree self-segments via
     ``models.common.merge_attn_partials``/``combine_attn_parts``."""
-    np_, bs, hk, dh = pool_k.shape
-    k_flat = pool_k.reshape(np_ * bs, hk, dh)
-    v_flat = pool_v.reshape(np_ * bs, hk, dh)
-    fn = (functools.partial(sparse_verify_attention_pallas, block_size=bs,
-                            interpret=_interpret())
-          if use_pallas else
-          functools.partial(ref.sparse_verify_attention_ref, block_size=bs))
-    return jax.vmap(fn, in_axes=(0, None, None, 0, 0))(
-        q, k_flat, v_flat, block_idx.astype(jnp.int32),
-        block_valid_len.astype(jnp.int32))
+    fn = jax.vmap(functools.partial(_block_attention, use_pallas=use_pallas),
+                  in_axes=(0, None, None, 0, 0))
+    return _per_shard(fn, (q, pool_k, pool_v, block_idx.astype(jnp.int32),
+                         block_valid_len.astype(jnp.int32)),
+                    (2, 2, 2, 1, 1), (1, 1, 1), pool_k.shape[2])
 
 
 @functools.partial(jax.jit, static_argnames=("use_pallas",))
@@ -164,21 +206,19 @@ def paged_prefill_attention(q, pool_k, pool_v, page_table, length, t_valid,
     discards.
     Returns normalised attention [B, T, H, Dh] in q's dtype (same
     contract as the flash fallback)."""
-    np_, bs, hk, dh = pool_k.shape
-    b, nb = page_table.shape
-    k_flat = pool_k.reshape(np_ * bs, hk, dh)
-    v_flat = pool_v.reshape(np_ * bs, hk, dh)
+    bs = pool_k.shape[1]
+    t = q.shape[1]
+    nb = page_table.shape[1]
     end = length + t_valid
     vlen = jnp.clip(end[:, None] - jnp.arange(nb)[None] * bs, 0, bs)
     routed = jnp.where(vlen > 0, page_table, 0)
-    idx = jnp.broadcast_to(routed[:, None], (b, hk, nb)).astype(jnp.int32)
-    vlen_h = jnp.broadcast_to(vlen[:, None], (b, hk, nb)).astype(jnp.int32)
-    qoff = length.astype(jnp.int32)[:, None]               # [B, 1]
-    fn = (functools.partial(paged_prefill_attention_pallas, block_size=bs,
-                            interpret=_interpret())
-          if use_pallas else
-          functools.partial(ref.paged_prefill_attention_ref, block_size=bs))
-    m, l, acc = jax.vmap(fn, in_axes=(0, None, None, 0, 0, 0))(
-        q, k_flat, v_flat, idx, vlen_h, qoff)
+    q_pos = length.astype(jnp.int32)[:, None] + jnp.arange(t)[None]
+    fn = jax.vmap(functools.partial(_block_attention, use_pallas=use_pallas),
+                  in_axes=(0, None, None, 0, 0, 0))
+    m, l, acc = _per_shard(fn, (q, pool_k, pool_v,
+                              routed[:, None].astype(jnp.int32),
+                              vlen[:, None].astype(jnp.int32), q_pos),
+                         (2, 2, 2, None, None, None), (1, 1, 1),
+                         pool_k.shape[2])
     out = acc / jnp.maximum(l, 1e-30)[..., None]           # [B, H, T, Dh]
     return out.transpose(0, 2, 1, 3).astype(q.dtype)

@@ -1,21 +1,34 @@
-"""Pallas TPU kernel: block-sparse verification attention — the paper's
-hot spot, adapted to TPU (DESIGN.md §Hardware adaptation).
+"""Pallas TPU kernel: block-routed flash attention over a paged KV pool —
+the paper's hot spot (verification attention) and chunked prefill.
 
-This is a paged/block-sparse flash attention: the selected block ids (the
-partial cache's page table) arrive via *scalar prefetch*, and the KV
-BlockSpec index_map uses them so the pipeline streams exactly the selected
-128-token KV tiles HBM->VMEM — the partial cache is never materialised.
-Running (m, l, acc) live in VMEM scratch; the final grid step emits
-softmax partials that the caller merges with the small buffer/tree segment
-(models.common.combine_attn_parts).
+The block ids to read (a slot's page table, or the retrieval-selected
+blocks of its partial cache) arrive via *scalar prefetch*, and the KV
+BlockSpec index_map uses them so the pipeline streams exactly those
+128-token KV tiles HBM->VMEM — neither the partial cache nor a gathered
+``[B, S_max]`` view is ever materialised.  Running (m, l, acc) live in
+VMEM scratch; the final grid step emits softmax partials that the caller
+merges with the small buffer/tree segment
+(``models.common.combine_attn_parts``).
 
-Grid: (Hk, NSel).  Per step: one KV block tile [bs, Dh] against the head's
-grouped queries [rep, T, Dh] — two MXU matmuls per tile.
+Layout.  The pool ``[NB, bs, Hk, Dh]`` is viewed as ``[NB, bs, Hk*Dh]``
+(a free reshape), and kv head ``h`` reads the ``(bs, Dh)`` tile at
+column block ``h`` — both tile dims are TPU-aligned.  A kv head's
+grouped queries are flattened to ``R = rep*T`` rows so each grid step is
+two plain 2-D MXU matmuls ``[R, Dh] x [Dh, bs]`` and ``[R, bs] x [bs, Dh]``.
+
+Masking.  Key ``s`` of grid block ``j`` is valid while
+``s < block_valid_len[h, j]``; with ``q_pos`` given (prefill) it must
+also satisfy the causal bound ``j*bs + s <= q_pos`` in absolute
+positions — grid coord ``j`` IS the logical block — so in-chunk
+self-attention falls out of the same scan.
+
+Grid: (Hk, N).
 """
 from __future__ import annotations
 
 import functools
 import math
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -25,9 +38,11 @@ from jax.experimental.pallas import tpu as pltpu
 NEG = -1e30
 
 
-def _kernel(idx_ref, vlen_ref, q_ref, k_ref, v_ref,
-            m_out, l_out, acc_out, m_s, l_s, acc_s, *,
-            block_size: int, nsel: int):
+def _kernel(idx_ref, vlen_ref, *refs, block_size: int, nblk: int,
+            per_head: bool, causal: bool):
+    if causal:
+        qpos_ref, *refs = refs
+    q_ref, k_ref, v_ref, m_out, l_out, acc_out, m_s, l_s, acc_s = refs
     h = pl.program_id(0)
     j = pl.program_id(1)
 
@@ -37,84 +52,91 @@ def _kernel(idx_ref, vlen_ref, q_ref, k_ref, v_ref,
         l_s[...] = jnp.zeros_like(l_s)
         acc_s[...] = jnp.zeros_like(acc_s)
 
-    q = q_ref[0].astype(jnp.float32)                      # [rep, T, Dh]
-    k = k_ref[0, :, 0].astype(jnp.float32)                # [bs, Dh]
-    v = v_ref[0, :, 0].astype(jnp.float32)                # [bs, Dh]
-    rep, t, dh = q.shape
+    q = q_ref[0].astype(jnp.float32)                      # [R, Dh]
+    k = k_ref[0].astype(jnp.float32)                      # [bs, Dh]
+    v = v_ref[0].astype(jnp.float32)                      # [bs, Dh]
+    logits = jax.lax.dot_general(
+        q, k, (((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32)               # [R, bs]
+    s_pos = jax.lax.broadcasted_iota(jnp.int32, logits.shape, 1)
+    ok = s_pos < vlen_ref[h if per_head else 0, j]
+    if causal:
+        ok = ok & (j * block_size + s_pos <= qpos_ref[...])
+    logits = jnp.where(ok, logits, NEG)
 
-    logits = jnp.einsum("rtd,sd->rts", q, k)              # [rep, T, bs]
-    nvalid = vlen_ref[h, j]
-    svalid = (jax.lax.broadcasted_iota(jnp.int32, (1, 1, block_size), 2)
-              < nvalid)
-    logits = jnp.where(svalid, logits, NEG)
-
-    m_prev = m_s[...]
-    m_new = jnp.maximum(m_prev, jnp.max(logits, axis=-1))
-    p = jnp.exp(logits - m_new[..., None]) * svalid
+    m_prev = m_s[...]                                     # [R, 1]
+    m_new = jnp.maximum(m_prev, jnp.max(logits, axis=-1, keepdims=True))
+    p = jnp.where(ok, jnp.exp(logits - m_new), 0.0)
     corr = jnp.exp(m_prev - m_new)
-    l_s[...] = l_s[...] * corr + jnp.sum(p, axis=-1)
-    acc_s[...] = (acc_s[...] * corr[..., None]
-                  + jax.lax.dot_general(
-                      p.reshape(rep * t, block_size), v,
-                      (((1,), (0,)), ((), ()))).reshape(rep, t, dh))
+    l_s[...] = l_s[...] * corr + jnp.sum(p, axis=-1, keepdims=True)
+    acc_s[...] = acc_s[...] * corr + jax.lax.dot_general(
+        p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
     m_s[...] = m_new
 
-    @pl.when(j == nsel - 1)
+    @pl.when(j == nblk - 1)
     def _emit():
         m_out[0] = m_s[...]
         l_out[0] = l_s[...]
         acc_out[0] = acc_s[...]
 
 
-def sparse_verify_attention_pallas(q, k_cache, v_cache, block_idx,
-                                   block_valid_len, block_size: int, *,
-                                   interpret: bool = True):
-    """q: [T, H, Dh]; k_cache/v_cache: [S, Hk, Dh];
-    block_idx/block_valid_len: [Hk, NSel] int32.
+def block_attention_pallas(q, k_blocks, v_blocks, block_idx,
+                           block_valid_len, q_pos: Optional[jax.Array] = None,
+                           *, interpret: bool = True):
+    """Softmax partials of ``q`` over routed KV blocks.
+
+    q: [T, H, Dh]; k_blocks/v_blocks: [NB, bs, Hk, Dh] (a page pool, or a
+    contiguous cache viewed as blocks); block_idx/block_valid_len:
+    [Hi, N] int32 with Hi = Hk (per-kv-head routing) or 1 (every head
+    reads the same blocks) — grid step j reads block ``block_idx[h, j]``
+    and keeps its first ``block_valid_len[h, j]`` keys; q_pos: [T] int32
+    absolute query positions, given for causal (prefill) attention where
+    grid step j is logical block j.
 
     Returns softmax partials (m [H, T], l [H, T], acc [H, T, Dh]) fp32."""
     t, h, dh = q.shape
-    s, hk, _ = k_cache.shape
-    nsel = block_idx.shape[1]
+    nb, bs, hk, _ = k_blocks.shape
+    hi, nblk = block_idx.shape
     rep = h // hk
-    nb = s // block_size
+    r = rep * t
+    causal = q_pos is not None
     scale = 1.0 / math.sqrt(dh)
     qg = (q.reshape(t, hk, rep, dh).transpose(1, 2, 0, 3)
-          * scale)                                         # [Hk, rep, T, Dh]
-    kb = k_cache[: nb * block_size].reshape(nb, block_size, hk, dh)
-    vb = v_cache[: nb * block_size].reshape(nb, block_size, hk, dh)
+          * scale).reshape(hk, r, dh)                      # [Hk, R, Dh]
+    kf = k_blocks.reshape(nb, bs, hk * dh)
+    vf = v_blocks.reshape(nb, bs, hk * dh)
 
+    def kv_map(hh, jj, idx, *_):
+        return idx[hh if hi > 1 else 0, jj], 0, hh
+
+    def head_map(hh, jj, *_):
+        return hh, 0, 0
+
+    in_specs = [pl.BlockSpec((1, r, dh), head_map),
+                pl.BlockSpec((1, bs, dh), kv_map),
+                pl.BlockSpec((1, bs, dh), kv_map)]
+    operands = [qg, kf, vf]
+    if causal:
+        in_specs.insert(0, pl.BlockSpec((r, 1), lambda *_: (0, 0)))
+        operands.insert(0, jnp.tile(q_pos.astype(jnp.int32), rep)[:, None])
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(hk, nsel),
-        in_specs=[
-            pl.BlockSpec((1, rep, t, dh),
-                         lambda hh, jj, idx, vl: (hh, 0, 0, 0)),
-            pl.BlockSpec((1, block_size, 1, dh),
-                         lambda hh, jj, idx, vl: (idx[hh, jj], 0, hh, 0)),
-            pl.BlockSpec((1, block_size, 1, dh),
-                         lambda hh, jj, idx, vl: (idx[hh, jj], 0, hh, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, rep, t), lambda hh, jj, idx, vl: (hh, 0, 0)),
-            pl.BlockSpec((1, rep, t), lambda hh, jj, idx, vl: (hh, 0, 0)),
-            pl.BlockSpec((1, rep, t, dh),
-                         lambda hh, jj, idx, vl: (hh, 0, 0, 0)),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((rep, t), jnp.float32),
-            pltpu.VMEM((rep, t), jnp.float32),
-            pltpu.VMEM((rep, t, dh), jnp.float32),
-        ],
+        grid=(hk, nblk),
+        in_specs=in_specs,
+        out_specs=[pl.BlockSpec((1, r, 1), head_map),
+                   pl.BlockSpec((1, r, 1), head_map),
+                   pl.BlockSpec((1, r, dh), head_map)],
+        scratch_shapes=[pltpu.VMEM((r, 1), jnp.float32),
+                        pltpu.VMEM((r, 1), jnp.float32),
+                        pltpu.VMEM((r, dh), jnp.float32)],
     )
-    out_shape = [
-        jax.ShapeDtypeStruct((hk, rep, t), jnp.float32),
-        jax.ShapeDtypeStruct((hk, rep, t), jnp.float32),
-        jax.ShapeDtypeStruct((hk, rep, t, dh), jnp.float32),
-    ]
+    out_shape = [jax.ShapeDtypeStruct((hk, r, 1), jnp.float32),
+                 jax.ShapeDtypeStruct((hk, r, 1), jnp.float32),
+                 jax.ShapeDtypeStruct((hk, r, dh), jnp.float32)]
     fn = pl.pallas_call(
-        functools.partial(_kernel, block_size=block_size, nsel=nsel),
+        functools.partial(_kernel, block_size=bs, nblk=nblk,
+                          per_head=hi > 1, causal=causal),
         grid_spec=grid_spec, out_shape=out_shape, interpret=interpret)
     idx = jnp.clip(block_idx.astype(jnp.int32), 0, nb - 1)
-    m, l, acc = fn(idx, block_valid_len.astype(jnp.int32), qg, kb, vb)
+    m, l, acc = fn(idx, block_valid_len.astype(jnp.int32), *operands)
     return (m.reshape(h, t), l.reshape(h, t), acc.reshape(h, t, dh))

@@ -51,8 +51,7 @@ def _kernel(r_ref, k_ref, v_ref, w_ref, u_ref, s0_ref, y_ref, sout_ref,
     def step(i, s):
         kv = k[i][:, None] * v[i][None, :]                # [dk, dk]
         y = (r[i][None] @ (s + u[:, None] * kv))[0]       # [dk]
-        pl.store(y_ref, (pl.dslice(i, 1), slice(None), slice(None)),
-                 y[None, None].astype(y_ref.dtype))
+        y_ref[pl.ds(i, 1)] = y[None, None].astype(y_ref.dtype)
         return w[i][:, None] * s + kv
 
     s = jax.lax.fori_loop(0, chunk, step, s_scr[...])

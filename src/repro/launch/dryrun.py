@@ -37,7 +37,7 @@ from repro.models.dense import attn_layer_count
 from repro.distributed.sharding import (ShardingRules, param_shardings,
                                         cache_shardings, batch_spec,
                                         pkv_shardings)
-from repro.launch.mesh import make_production_mesh, use_mesh
+from repro.launch.mesh import make_production_mesh
 from repro.launch import steps as st
 from repro.train.optimizer import adamw_init
 
@@ -164,7 +164,7 @@ def run_case(arch: str, shape_name: str, mesh_name: str,
         mesh = make_production_mesh(multi_pod=(mesh_name == "multipod"))
         t0 = time.time()
         fn, args, donate = build_case(arch, shape_name, mesh, spec)
-        with use_mesh(mesh):
+        with jax.set_mesh(mesh):
             lowered = jax.jit(fn, donate_argnums=donate).lower(*args)
         res["lower_s"] = round(time.time() - t0, 2)
         t0 = time.time()

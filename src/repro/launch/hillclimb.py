@@ -40,7 +40,7 @@ from repro.models.dense import attn_layer_count
 from repro.distributed.sharding import (ShardingRules, param_shardings,
                                         cache_shardings, batch_spec,
                                         pkv_shardings)
-from repro.launch.mesh import make_production_mesh, use_mesh
+from repro.launch.mesh import make_production_mesh
 from repro.launch.hlo_analysis import parse_collective_bytes
 from repro.launch.dryrun import _sds, _shard_tree
 
@@ -338,7 +338,7 @@ def run_case(name: str) -> dict:
         mesh = make_production_mesh()
         t0 = time.time()
         fn, args, donate = CASES[name](mesh)
-        with use_mesh(mesh):
+        with jax.set_mesh(mesh):
             lowered = jax.jit(fn, donate_argnums=donate).lower(*args)
         res["lower_s"] = round(time.time() - t0, 2)
         t0 = time.time()

@@ -10,25 +10,23 @@ importing this module does not touch jax device state.
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
 
-def use_mesh(mesh):
-    """Version-portable mesh context manager.
-
-    ``jax.set_mesh`` only exists on jax >= 0.6; on the pinned 0.4.x the
-    ``Mesh`` object itself is a context manager installing the thread-local
-    physical mesh, which is what ``repro.models.common.current_mesh`` falls
-    back to."""
-    setter = getattr(jax, "set_mesh", None)
-    if setter is not None:
-        return setter(mesh)
-    return mesh
+def make_mesh(shape, axes, *, devices=None):
+    """A device mesh whose axes are all ``Auto``: placements come from
+    the operands' ``NamedSharding``s and GSPMD propagates them through
+    each jitted step (``jax.make_mesh`` otherwise defaults to
+    ``Explicit`` axes, which type-check every op's sharding)."""
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(AxisType.Auto,) * len(shape),
+                         devices=devices)
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def data_axes(mesh) -> tuple:

@@ -128,26 +128,9 @@ def cdiv_(a: int, b: int) -> int:
 
 
 def current_mesh():
-    """The ambient mesh, or None outside any mesh context.
-
-    ``jax.sharding.get_abstract_mesh`` only exists in jax >= 0.5; on the
-    pinned 0.4.x we fall back to the thread-local physical mesh that
-    ``with mesh:`` / ``jax.sharding.use_mesh`` installs.  Both objects
-    expose ``axis_names`` and a ``shape`` mapping, which is all callers
-    use."""
-    get_abstract = getattr(jax.sharding, "get_abstract_mesh", None)
-    if get_abstract is not None:
-        try:
-            m = get_abstract()
-        except Exception:
-            m = None
-        if m is not None and m.axis_names:
-            return m
-    try:
-        from jax.interpreters import pxla
-        m = pxla.thread_resources.env.physical_mesh
-    except Exception:
-        return None
+    """The ambient mesh (``jax.set_mesh``), or None outside any mesh
+    context.  Callers use its ``axis_names`` and ``shape`` mapping."""
+    m = jax.sharding.get_abstract_mesh()
     return m if m.axis_names else None
 
 

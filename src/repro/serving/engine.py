@@ -115,8 +115,8 @@ class ServingConfig:
     # (8, 1).  The continuous engine shards its batch rows, page pools
     # and page tables over the ``data`` axis (per-host page pools — no
     # host materializes the whole cache or batch) and trunk weights
-    # over ``model`` (see docs/architecture.md#mesh--sharding).  None,
-    # or a shape the local device count cannot satisfy, runs unsharded.
+    # over ``model`` (see docs/architecture.md#mesh--sharding).  None
+    # runs unsharded; a shape the devices cannot fill raises.
     mesh_shape: Optional[tuple] = None
 
 
@@ -155,15 +155,20 @@ class ServingEngine:
 
     def _mesh(self):
         """The serving mesh per ``ServingConfig.mesh_shape`` (None when
-        unsharded or the local device count cannot fill the shape)."""
+        unsharded).  Raises when the devices cannot fill the shape: a
+        sharded deployment never silently runs on one device."""
         shape = self.scfg.mesh_shape
         if shape is None:
             return None
         import jax
         import math
+        from repro.launch.mesh import make_mesh
         if math.prod(shape) > jax.device_count():
-            return None
-        return jax.make_mesh(tuple(shape), ("data", "model"))
+            raise ValueError(
+                f"mesh_shape {tuple(shape)} needs {math.prod(shape)} "
+                f"devices; {jax.device_count()} available")
+        return make_mesh(shape, ("data", "model"),
+                         devices=jax.devices()[:math.prod(shape)])
 
     def _engine_for(self, batch: int, *, paged: bool = False) -> SpecPVEngine:
         key = (batch, paged)

@@ -9,12 +9,12 @@ import numpy as np
 from repro.configs import SpecPVConfig
 from repro.distributed.cp_retrieval import cp_partial_verify_attention
 from repro.kernels import ref
-from repro.launch.mesh import use_mesh
+from repro.launch.mesh import make_mesh
 from repro.models import common as cm
 
 
 def test_cp_retrieval_single_shard_matches_global():
-    mesh = jax.make_mesh((1,), ("model",))
+    mesh = make_mesh((1,), ("model",))
     spec = SpecPVConfig(block_size=16)
     b, s, hk, dh, h, t = 1, 128, 2, 32, 4, 3
     k = jax.random.normal(jax.random.PRNGKey(0), (b, s, hk, dh))
@@ -24,7 +24,7 @@ def test_cp_retrieval_single_shard_matches_global():
     km, kn = jax.vmap(lambda kk, ll: ref.block_summary_ref(kk, ll, 16))(
         k, length)
     budget = 4
-    with use_mesh(mesh):
+    with jax.set_mesh(mesh):
         out = cp_partial_verify_attention(mesh, "model", spec, budget,
                                           q, k, v, km, kn, length)
     nb = s // 16

@@ -37,9 +37,9 @@ from repro.distributed import (cp_full_verify_attention,
                                gathered_blocks_bytes, merged_partials_bytes,
                                verify_traffic_report)
 from repro.kvcache.cache import PageAllocator, PrefixCache
-from repro.launch.mesh import use_mesh
+from repro.launch.mesh import make_mesh
 from repro.models import api
-from repro.serving import Request
+from repro.serving import Request, ServingConfig, ServingEngine
 from repro.serving.scheduler import ContinuousScheduler
 
 pytestmark = pytest.mark.sharded
@@ -155,10 +155,10 @@ def _qkv(b=2, s=128, hk=2, h=4, dh=16, t=3, seed=0):
 
 
 def test_cp_full_verify_single_shard_matches_dense():
-    mesh = jax.make_mesh((1,), ("model",))
+    mesh = make_mesh((1,), ("model",))
     q, k, v = _qkv()
     length = jnp.asarray([100, 128], jnp.int32)
-    with use_mesh(mesh):
+    with jax.set_mesh(mesh):
         out = cp_full_verify_attention(mesh, "model", q, k, v, length)
     np.testing.assert_allclose(np.asarray(out),
                                np.asarray(_dense_ref(q, k, v, length)),
@@ -169,10 +169,10 @@ def test_cp_full_verify_single_shard_matches_dense():
 def test_cp_full_verify_eight_shards_matches_dense():
     """The merge is exact even when shards hold zero valid keys (short
     rows): their ``m = -inf`` partials drop out of the psum."""
-    mesh = jax.make_mesh((8,), ("model",))
+    mesh = make_mesh((8,), ("model",))
     q, k, v = _qkv(s=256)
     length = jnp.asarray([20, 256], jnp.int32)   # row 0: 6 empty shards
-    with use_mesh(mesh):
+    with jax.set_mesh(mesh):
         out = cp_full_verify_attention(mesh, "model", q, k, v, length)
     np.testing.assert_allclose(np.asarray(out),
                                np.asarray(_dense_ref(q, k, v, length)),
@@ -189,7 +189,7 @@ def test_cp_retrieval_sharded_topk_divergence():
     nb = s // 16
     q, k, v = _qkv(b=b, s=s, hk=hk, h=h, dh=dh, t=t, seed=3)
     length = jnp.asarray([s], jnp.int32)
-    mesh = jax.make_mesh((8,), ("model",))
+    mesh = make_mesh((8,), ("model",))
 
     # engineered: one standout block per shard -> local == global top-8
     k_eng = k * 0.01
@@ -200,7 +200,7 @@ def test_cp_retrieval_sharded_topk_divergence():
         from repro.kernels import ref
         km, kn = jax.vmap(lambda kk, ll: ref.block_summary_ref(kk, ll, 16))(
             keys, length)
-        with use_mesh(mesh):
+        with jax.set_mesh(mesh):
             out = cp_partial_verify_attention(mesh, "model", spec, 8,
                                               q, keys, v, km, kn, length)
         # global top-8 reference
@@ -331,7 +331,7 @@ def test_prefix_snapshot_structure_only_restores_nothing():
 @needs8
 def test_shard_of_slot_matches_named_sharding():
     from jax.sharding import NamedSharding, PartitionSpec as P
-    mesh = jax.make_mesh((8, 1), ("data", "model"))
+    mesh = make_mesh((8, 1), ("data", "model"))
     x = jax.device_put(jnp.arange(8), NamedSharding(mesh, P("data")))
     order = {d.id: i for i, d in enumerate(mesh.devices.flatten())}
     for sh in x.addressable_shards:
@@ -372,7 +372,7 @@ def _serve(eng, prompts):
 @pytest.mark.slow
 def test_mesh_size_one_engine_token_identical(tiny, small_spec, small_dcfg):
     cfg, params, dparams = tiny
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     base = SpecPVEngine(cfg, small_spec, small_dcfg, params, dparams,
                         batch=2, max_len=MAX_LEN,
                         partial_verification=True, paged=True)
@@ -382,6 +382,19 @@ def test_mesh_size_one_engine_token_identical(tiny, small_spec, small_dcfg):
     assert meshed.data_shards == 1
     prompts = _prompts(cfg, np.random.default_rng(7), 3)
     assert _serve(base, prompts) == _serve(meshed, prompts)
+
+
+def test_unfillable_mesh_shape_raises(tiny, small_spec, small_dcfg):
+    """A mesh_shape the devices cannot fill is an error, never a silent
+    single-device run."""
+    cfg, params, dparams = tiny
+    srv = ServingEngine(cfg, small_spec, small_dcfg, params, dparams,
+                        ServingConfig(batch=2, max_len=MAX_LEN, paged_kv=True,
+                                      mesh_shape=(NDEV + 1, 1)))
+    srv.submit(Request(request_id="r", prompt=np.arange(1, 9, dtype=np.int32),
+                       max_new_tokens=2))
+    with pytest.raises(ValueError, match="mesh_shape"):
+        srv.run()
 
 
 @pytest.mark.slow
@@ -394,7 +407,7 @@ def test_data_sharded_serving_token_identical(tiny, small_spec, small_dcfg):
     base = SpecPVEngine(cfg, small_spec, small_dcfg, params, dparams,
                         batch=8, max_len=MAX_LEN,
                         partial_verification=True, paged=True)
-    mesh = jax.make_mesh((8, 1), ("data", "model"))
+    mesh = make_mesh((8, 1), ("data", "model"))
     meshed = SpecPVEngine(cfg, small_spec, small_dcfg, params, dparams,
                           batch=8, max_len=MAX_LEN,
                           partial_verification=True, paged=True, mesh=mesh)
@@ -415,7 +428,7 @@ def test_fork_cow_refcounts_under_sharding(tiny, small_spec, small_dcfg):
     """An engine fork on a sharded pool shares pages within the shard
     and CoW isolates the fork — refcounts and free counts balance."""
     cfg, params, dparams = tiny
-    mesh = jax.make_mesh((4, 1), ("data", "model"))
+    mesh = make_mesh((4, 1), ("data", "model"))
     eng = SpecPVEngine(cfg, small_spec, small_dcfg, params, dparams,
                        batch=8, max_len=MAX_LEN,
                        partial_verification=True, paged=True, mesh=mesh)

@@ -1,0 +1,90 @@
+"""AOT compiles of the serving path's Pallas kernels for TPU v5e.
+
+Interpret mode (every other kernel test) cannot see what the TPU's
+compiler refuses: block shapes whose last two dims are not (8, 128)
+tiles, VMEM overflow, unpartitionable calls.  These tests compile each
+kernel wrapper of ``repro.kernels.ops`` at Qwen3-8B widths (8 KV heads
+of 128, 32 query heads, 128-token blocks, bf16) for a described
+``v5e:2x2`` topology — no chip needed — and assert the compiled program
+calls the Mosaic kernel (``tpu_custom_call``).
+
+The topology is described inside a module-scoped fixture (never at
+import time): only one process may load the TPU library at a time, so
+the call happens in the one worker that runs this file.
+"""
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.kernels import ops
+
+B, T, TP, H, HK, DH, BS = 4, 61, 512, 32, 8, 128, 128
+NB = 10240 // BS                   # blocks per slot at max_len 10240
+NP = B * NB + 1                    # pool pages incl. the null page
+NS = 35                            # partial budget: 1 + 32 + 2 blocks
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    # the kernels trace differently for the chip (interpret off): start
+    # and end with empty trace caches so no CPU trace is reused here and
+    # none of these leaks into later tests of this worker
+    jax.clear_caches()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.clear_caches()
+
+
+@pytest.fixture
+def tpu_kernels(monkeypatch):
+    monkeypatch.setattr(ops, "_interpret", lambda: False)
+
+
+def _sds(sharding, shape, dtype):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+def _cases(sh):
+    bf, f32, i32 = jnp.bfloat16, jnp.float32, jnp.int32
+    pool = _sds(sh, (NP, BS, HK, DH), bf)
+    q = _sds(sh, (B, T, H, DH), bf)
+    return {
+        "block_summaries": (
+            lambda k, n: ops.block_summaries(k, n, BS),
+            (_sds(sh, (B, NB * BS, HK, DH), bf), _sds(sh, (B,), i32))),
+        "retrieval_scores": (
+            ops.retrieval_scores,
+            (q, _sds(sh, (B, NB, HK, DH), f32), _sds(sh, (B, NB, HK, DH), f32),
+             _sds(sh, (B, T), f32))),
+        "paged_verify_attention": (
+            ops.paged_verify_attention,
+            (q, pool, pool, _sds(sh, (B, NB), i32), _sds(sh, (B,), i32))),
+        "routed_partial_attention": (
+            ops.routed_partial_attention,
+            (q, pool, pool, _sds(sh, (B, HK, NS), i32),
+             _sds(sh, (B, HK, NS), i32))),
+        "paged_prefill_attention": (
+            ops.paged_prefill_attention,
+            (_sds(sh, (B, TP, H, DH), bf), pool, pool, _sds(sh, (B, NB), i32),
+             _sds(sh, (B,), i32), _sds(sh, (B,), i32))),
+    }
+
+
+@pytest.mark.parametrize("name", ["block_summaries", "retrieval_scores",
+                                  "paged_verify_attention",
+                                  "routed_partial_attention",
+                                  "paged_prefill_attention"])
+def test_kernel_compiles_for_v5e(name, one_chip, tpu_kernels):
+    fn, args = _cases(one_chip)[name]
+    compiled = jax.jit(fn).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()

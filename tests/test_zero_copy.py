@@ -250,8 +250,11 @@ def test_zero_copy_token_identity_tiered(tiny, small_spec, small_dcfg):
     gat = _mk_engine(tiny, small_spec, small_dcfg, batch=2, **kw)
     rtd = _mk_engine(tiny, small_spec, small_dcfg, batch=2,
                      zero_copy=True, **kw)
-    reqs = [_mk_req(cfg, "a", 200, 16, seed=2),
-            _mk_req(cfg, "b", 256, 16, seed=3)]
+    # long enough that the pin set (the union of every layer's and kv
+    # head's retrieved blocks) cannot cover every committed block, so
+    # some blocks are left for the tier to demote
+    reqs = [_mk_req(cfg, "a", 320, 16, seed=2),
+            _mk_req(cfg, "b", 448, 16, seed=3)]
     sg = _run_sched(gat, list(reqs))
     sr = _run_sched(rtd, [_mk_req(cfg, r.request_id, len(r.prompt), 16,
                                   seed=2 if r.request_id == "a" else 3)
