@@ -80,9 +80,9 @@ budget-straddling Poisson request set runs once with refreshes copying
 the selected blocks into the dense partial buffer and once with
 refreshes writing O(budget) selected-block indices and pinning the
 pages (the partial body reads route through the trunk pool).  Reports
-decode-step gap p50/p95, refresh-tick p50/p95 from the scheduler's
-per-class tick wall-time breakdown, each arm's billed refresh HBM
-traffic, the pin drain check (zero pinned pages after the run), and
+decode-step gap p50/p95, the mean decode wall time per tick class from
+the scheduler's ``sched.decode.<class>`` span counters, each arm's
+billed refresh HBM traffic, the pin drain check (zero pinned pages after the run), and
 verifies the two arms produce token-identical outputs.
 
 ``--sampled`` A/Bs greedy vs stochastic serving through the same fused
@@ -254,6 +254,18 @@ def step_gap_stats(step_log):
         times.setdefault(rid, []).append(t)
     gaps = [g for ts in times.values() for g in np.diff(ts) if len(ts) > 1]
     return np.asarray(gaps, np.float64)
+
+
+def tick_class_walls(stats):
+    """{tick class: (decode ticks, mean decode wall seconds)} from the
+    scheduler's ``sched.decode.<class>`` span counters."""
+    out = {}
+    for key, n in stats.items():
+        if key.startswith("span_n.sched.decode.") and n:
+            name = key[len("span_n."):]
+            out[name.rsplit(".", 1)[1]] = (int(n),
+                                           stats["span_s." + name] / n)
+    return out
 
 
 def run_interleave(args, cfg, dcfg, params, dparams, corpus, spec,
@@ -817,8 +829,8 @@ def run_zero_copy(args, cfg, dcfg, params, dparams, corpus, spec,
     gathered refresh copies the selected blocks' bytes into the dense
     per-slot partial buffer; a routed refresh writes O(budget) selected
     block indices and pins the pages.  Reports decode-step gap p50/p95,
-    the per-class tick wall-time breakdown (refresh ticks are the ones
-    the tentpole targets), refresh-tick p50/p95, modelled refresh HBM
+    the per-class mean tick wall time (refresh ticks are the ones the
+    tentpole targets), modelled refresh HBM
     traffic of each billing contract, and the pin high-water/drain —
     outputs are verified token-identical."""
     rng = np.random.default_rng(args.seed)
@@ -867,21 +879,16 @@ def run_zero_copy(args, cfg, dcfg, params, dparams, corpus, spec,
         toks = sum(len(o.tokens) for o in outs)
         gaps = step_gap_stats(sched.step_log)
         g50, g95 = percentiles(gaps) if gaps.size else (0.0, 0.0)
-        rticks = np.asarray(sched.tick_wall.get("refresh", []) or [0.0])
-        r50, r95 = percentiles(rticks)
-        walls = {c: (len(ts), float(np.mean(ts)))
-                 for c, ts in sched.tick_wall.items()}
+        walls = tick_class_walls(sched.stats)
         ps = eng.page_stats()
         pins = int(ps.get("pinned_pages", 0))
         rbytes = int(eng.traffic.bytes_by_mode.get("refresh", 0))
         results[mode] = dict(outs=outs, tput=toks / wall, g50=g50,
-                             g95=g95, r50=r50, r95=r95, walls=walls,
-                             pins=pins, rbytes=rbytes,
-                             rticks=int(rticks.size))
+                             g95=g95, walls=walls,
+                             pins=pins, rbytes=rbytes)
         print(f"{mode:>9}: {toks} tokens in {wall:.1f}s -> "
               f"{toks / wall:.1f} tok/s; decode-gap p50={g50 * 1e3:.1f}ms "
-              f"p95={g95 * 1e3:.1f}ms; refresh-tick p50={r50 * 1e3:.1f}ms "
-              f"p95={r95 * 1e3:.1f}ms over {rticks.size} refresh ticks")
+              f"p95={g95 * 1e3:.1f}ms")
         print(f"{'':>9}  tick wall by class: "
               + ", ".join(f"{c}: {n}x {m * 1e3:.1f}ms"
                           for c, (n, m) in sorted(walls.items()))
@@ -899,20 +906,20 @@ def run_zero_copy(args, cfg, dcfg, params, dparams, corpus, spec,
         print("losslessness: zero-copy (routed) outputs token-identical "
               "to the gathered-partial baseline")
     rg, rr = results["gathered"], results["routed"]
-    print(f"refresh-tick p95: {rr['r95'] * 1e3:.1f}ms routed vs "
-          f"{rg['r95'] * 1e3:.1f}ms gathered "
-          f"({rg['r95'] / max(rr['r95'], 1e-9):.2f}x); billed refresh "
+    rmean = {m: r["walls"].get("refresh", (0, 0.0)) for m, r in
+             results.items()}
+    print(f"refresh-tick mean: {rmean['routed'][1] * 1e3:.1f}ms routed vs "
+          f"{rmean['gathered'][1] * 1e3:.1f}ms gathered; billed refresh "
           f"traffic {rg['rbytes'] / max(rr['rbytes'], 1):.2f}x smaller "
           f"routed (rebuild-term model: benchmarks/bench_fig6_refresh.py)")
     out = ensure_dir(RESULTS_DIR)
     write_rows(f"{out}/bench_serving_zero_copy.csv",
                ["mode", "tok_s", "gap_p50_ms", "gap_p95_ms",
-                "refresh_tick_p50_ms", "refresh_tick_p95_ms",
-                "refresh_ticks", "refresh_bytes", "pinned_pages_after"],
+                "refresh_tick_mean_ms", "refresh_ticks", "refresh_bytes",
+                "pinned_pages_after"],
                [[m, f"{r['tput']:.2f}", f"{r['g50'] * 1e3:.2f}",
-                 f"{r['g95'] * 1e3:.2f}", f"{r['r50'] * 1e3:.2f}",
-                 f"{r['r95'] * 1e3:.2f}", r["rticks"], r["rbytes"],
-                 r["pins"]]
+                 f"{r['g95'] * 1e3:.2f}", f"{rmean[m][1] * 1e3:.2f}",
+                 rmean[m][0], r["rbytes"], r["pins"]]
                 for m, r in results.items()])
 
 
@@ -1211,9 +1218,9 @@ def main():
     ap.add_argument("--zero-copy", action="store_true",
                     help="A/B gathered vs page-table-routed (zero-copy) "
                          "partial KV on the paged cache: decode-gap "
-                         "p50/p95, refresh-tick p50/p95, per-class tick "
-                         "wall breakdown, billed refresh traffic, pin "
-                         "drain check, token identity")
+                         "p50/p95, per-class mean tick wall time, billed "
+                         "refresh traffic, pin drain check, token "
+                         "identity")
     ap.add_argument("--tiered", action="store_true",
                     help="tiered-residency memory-pressure A/B: untiered "
                          "parity pool vs untiered + tiered (lossless and "

@@ -44,6 +44,7 @@ from __future__ import annotations
 
 import contextlib
 import functools
+from collections import defaultdict
 from dataclasses import dataclass, field, replace as dc_replace
 from typing import Any, Dict, List, Optional, Sequence, Tuple
 
@@ -58,6 +59,7 @@ from repro.core import draft as dr
 from repro.core import tree as tr
 from repro.core import verify as vf
 from repro.utils import pytree_dataclass, cdiv
+from repro.utils.spans import span
 from repro.kvcache import cache as kvc
 from repro.kvcache.offload import TierManager, TrafficMeter, \
     full_step_bytes, partial_step_bytes, routed_refresh_bytes
@@ -160,6 +162,7 @@ class PrefillCursor:
     temperature: float = 0.0
     seed: int = 0
     draft: str = "tree"
+    request_id: str = ""                # metadata of the cursor's spans
 
     @property
     def done(self) -> bool:
@@ -380,6 +383,9 @@ class SpecPVEngine:
         self._pkv_active_rows = np.zeros((batch,), bool)   # per-slot automaton
         self.dispatches = 0             # jitted engine steps executed
         self.prefill_dispatches = 0     # jitted prefill chunks launched
+        # span counters (repro.utils.spans); a ContinuousScheduler hands
+        # the engine its own stats, so both land in one mapping
+        self.stats: Dict[str, float] = defaultdict(float)
         self._prefix_dedups = 0         # duplicate blocks collapsed
         if self._rules is not None and jax.device_count() > 1:
             # place trunk + draft weights once; GSPMD propagates the
@@ -581,11 +587,14 @@ class SpecPVEngine:
             else:
                 key_draft = key_accept = None
                 keys_next = st.keys
-            dcache, tree_tokens, aux = dr.draft_phase(
-                cfg, dcfg, dparams, params, tree, st.dcache, st.ext_tokens,
-                st.ext_feats, st.ext_len, active=active,
-                sample_key=key_draft,
-                temperature=(st.temps if has_sampled else 0.0))
+            # device-side phase scopes: the ops' metadata (``tf_op`` in a
+            # profiler trace) names the phase of the step they belong to
+            with jax.named_scope("draft"):
+                dcache, tree_tokens, aux = dr.draft_phase(
+                    cfg, dcfg, dparams, params, tree, st.dcache,
+                    st.ext_tokens, st.ext_feats, st.ext_len, active=active,
+                    sample_key=key_draft,
+                    temperature=(st.temps if has_sampled else 0.0))
             node_valid = None
             if has_chain:
                 node_valid = (~is_chain[:, None]
@@ -610,31 +619,36 @@ class SpecPVEngine:
                 plen_in = jnp.ones((b,), jnp.int32)
                 p_eff = jnp.ones((b,), jnp.int32)
 
-            vin = vf.build_verify_inputs_fused(
-                tree, pend_in, plen_in, p_eff, tree_tokens, st.seq_len,
-                active=active)
             decode_kind = ("fused" if has_full and has_partial
                            else ("full" if has_full else "partial"))
-            out = api.decode(
-                cfg, params, vin["tokens"], vin["positions"], st.cache,
-                mode=decode_kind, self_mask=vin["self_mask"],
-                pkv=(st.pkv_k, st.pkv_v, st.pkv_pos), spec=spec,
-                emit_queries=has_refresh,
-                partial_rows=is_partial if decode_kind == "fused" else None,
-                # zero-copy: route partial rows' retrieved body through
-                # the live page table ([B, L, Hk, NS] -> [L, B, Hk, NS])
-                pkv_blocks=(jnp.moveaxis(st.pkv_blocks, 0, 1)
-                            if self.zero_copy and has_partial else None))
+            with jax.named_scope("verify"):
+                vin = vf.build_verify_inputs_fused(
+                    tree, pend_in, plen_in, p_eff, tree_tokens, st.seq_len,
+                    active=active)
+                out = api.decode(
+                    cfg, params, vin["tokens"], vin["positions"], st.cache,
+                    mode=decode_kind, self_mask=vin["self_mask"],
+                    pkv=(st.pkv_k, st.pkv_v, st.pkv_pos), spec=spec,
+                    emit_queries=has_refresh,
+                    partial_rows=(is_partial if decode_kind == "fused"
+                                  else None),
+                    # zero-copy: route partial rows' retrieved body
+                    # through the live page table ([B, L, Hk, NS] ->
+                    # [L, B, Hk, NS])
+                    pkv_blocks=(jnp.moveaxis(st.pkv_blocks, 0, 1)
+                                if self.zero_copy and has_partial else None))
 
-            path, acc, bonus = _accept(
-                tree_tokens, aux, out, vin, st, key_accept,
-                has_sampled=has_sampled, node_valid=node_valid)
-            newtoks, ext_feats, ext_len, seq_len = _post_accept(
-                st, vin, out, tree_tokens, path, acc, bonus)
+            with jax.named_scope("accept"):
+                path, acc, bonus = _accept(
+                    tree_tokens, aux, out, vin, st, key_accept,
+                    has_sampled=has_sampled, node_valid=node_valid)
+                newtoks, ext_feats, ext_len, seq_len = _post_accept(
+                    st, vin, out, tree_tokens, path, acc, bonus)
 
-            slots, slot_valid = vf.commit_slots(tree, vin["pend_valid"],
-                                                path, p_eff)
-            ck, cv = vf.gather_new_kv(out.new_kv, slots, slot_valid)
+            with jax.named_scope("commit"):
+                slots, slot_valid = vf.commit_slots(tree, vin["pend_valid"],
+                                                    path, p_eff)
+                ck, cv = vf.gather_new_kv(out.new_kv, slots, slot_valid)
             count = plen_in + acc
 
             cache = st.cache
@@ -656,9 +670,10 @@ class SpecPVEngine:
                 # zero-copy: the dense arrays hold only the buffer, so
                 # appends start at offset 0 instead of past the body
                 body_len = 0 if self.zero_copy else spec.partial_budget_tokens
-                nk, nv, npos, nbl = vf.append_buffer(
-                    pkv_k, pkv_v, pkv_pos, body_len,
-                    buf_len, ck[:, :, :wb], cv[:, :, :wb], cpos, count_buf)
+                with jax.named_scope("commit"):
+                    nk, nv, npos, nbl = vf.append_buffer(
+                        pkv_k, pkv_v, pkv_pos, body_len, buf_len,
+                        ck[:, :, :wb], cv[:, :, :wb], cpos, count_buf)
                 if has_full:   # non-partial rows keep their pkv bits
                     selp = is_partial[None, :, None, None]
                     pkv_k = jnp.where(selp[..., None], nk, pkv_k)
@@ -675,7 +690,9 @@ class SpecPVEngine:
                 # same bits, so length/summaries stay untouched
                 count_full = (jnp.where(is_partial, 0, count)
                               if has_partial else count)
-                cache = vf.append_full_cache(cache, ck, cv, count_full, spec)
+                with jax.named_scope("commit"):
+                    cache = vf.append_full_cache(cache, ck, cv, count_full,
+                                                 spec)
             if has_refresh:
                 # masked epilogue: rebuild refresh rows' partial cache
                 # from this step's queries (Quest retrieval over the
@@ -698,8 +715,9 @@ class SpecPVEngine:
                     # — no gathered body is ever materialised.  The
                     # host wrapper pins the selected physical pages
                     # right after this dispatch returns.
-                    nbi = vf.refresh_partial_blocks(
-                        cfg, spec, out.queries, qw, cache)
+                    with jax.named_scope("refresh"):
+                        nbi = vf.refresh_partial_blocks(
+                            cfg, spec, out.queries, qw, cache)
                     nbi = jnp.moveaxis(nbi, 0, 1)   # [B, L_attn, Hk, NS]
                     selb = is_refresh[:, None, None, None]
                     pkv_blocks = jnp.where(selb, nbi, pkv_blocks)
@@ -707,8 +725,9 @@ class SpecPVEngine:
                     pkv_pos = jnp.where(selr, -1, pkv_pos)
                     buf_len = jnp.where(is_refresh, 0, buf_len)
                 else:
-                    pk, pv, ppos = vf.refresh_partial_from_queries(
-                        cfg, spec, out.queries, qw, cache)
+                    with jax.named_scope("refresh"):
+                        pk, pv, ppos = vf.refresh_partial_from_queries(
+                            cfg, spec, out.queries, qw, cache)
                     pad = spec.buffer_size
                     rk = jnp.pad(pk,
                                  ((0, 0), (0, 0), (0, 0), (0, pad), (0, 0)))
@@ -1550,7 +1569,8 @@ class SpecPVEngine:
                            extra: Optional[Dict] = None,
                            max_new_tokens: Optional[int] = None,
                            temperature: Optional[float] = None,
-                           seed: int = 0, draft: str = "tree"
+                           seed: int = 0, draft: str = "tree",
+                           request_id: str = ""
                            ) -> Tuple[EngineState, PrefillCursor]:
         """Open a resumable prefill of `prompt` into batch row `slot`.
         Returns (state, cursor); drive the cursor with
@@ -1565,7 +1585,8 @@ class SpecPVEngine:
         reproducible regardless of batch composition or admission
         order.  ``draft="chain"`` serves the slot with single-chain
         verification (acceptance masked to the tree's rank-0 chain) in
-        the same fused tick as tree slots.
+        the same fused tick as tree slots.  ``request_id`` only labels
+        the cursor's prefill spans.
 
         All admission-time page accounting happens here, up front: the
         prefix cache is consulted (matched leading blocks attach by
@@ -1586,7 +1607,8 @@ class SpecPVEngine:
         cfg = self.cfg
         temp = (self.temperature if temperature is None
                 else float(temperature))
-        knobs = dict(temperature=temp, seed=int(seed), draft=draft)
+        knobs = dict(temperature=temp, seed=int(seed), draft=draft,
+                     request_id=request_id)
         if not self.paged:
             cur = PrefillCursor(
                 slot=slot, prompt=prompt, chunk=chunk, extra=extra, off=0,
@@ -1836,72 +1858,89 @@ class SpecPVEngine:
         s0 = len(cur.prompt)
         off = cur.off
         end = min(s0, (off // cur.chunk + 1) * cur.chunk)
-        toks = jnp.asarray(cur.prompt[None, off: end])
-        if self.paged:
-            sub_cache = {n: st.cache[n] for n in kvc.PAGED_POOL_KEYS}
-            sub_cache.update(cur.row_cache)
-            sub_dcache = {n: st.dcache[n] for n in kvc.DRAFT_POOL_KEYS}
-            sub_dcache.update(cur.row_dcache)
-        else:
-            sub_cache, sub_dcache = cur.row_cache, cur.row_dcache
-        with self._on_mesh():
-            cache, dcache, logits_last, fused = self._prefill_chunk(
-                self.params, self.dparams, sub_cache, sub_dcache, toks,
-                cur.prev_feat, cur.extra)
-        self.prefill_dispatches += 1
+        rid = cur.request_id
+        with span(self.stats, "engine.prefill.dispatch", request_id=rid):
+            toks = jnp.asarray(cur.prompt[None, off: end])
+            if self.paged:
+                sub_cache = {n: st.cache[n] for n in kvc.PAGED_POOL_KEYS}
+                sub_cache.update(cur.row_cache)
+                sub_dcache = {n: st.dcache[n] for n in kvc.DRAFT_POOL_KEYS}
+                sub_dcache.update(cur.row_dcache)
+            else:
+                sub_cache, sub_dcache = cur.row_cache, cur.row_dcache
+            with self._on_mesh():
+                cache, dcache, logits_last, fused = self._prefill_chunk(
+                    self.params, self.dparams, sub_cache, sub_dcache, toks,
+                    cur.prev_feat, cur.extra)
+            self.prefill_dispatches += 1
 
-        cur.prev_feat = fused[:, -1]
-        cur.logits_last = logits_last
-        if self.paged:
-            # the pools were written in place (batch-1 view); rebind them
-            # into the batched state so interleaved decode steps see the
-            # chunk, and keep only the per-row keys in the cursor
-            cur.row_cache = {n: v for n, v in cache.items()
-                             if n not in kvc.PAGED_POOL_KEYS}
-            cur.row_dcache = {n: v for n, v in dcache.items()
-                              if n not in kvc.DRAFT_POOL_KEYS}
-            pool = {n: cache[n] for n in kvc.PAGED_POOL_KEYS}
-            dpool = {n: dcache[n] for n in kvc.DRAFT_POOL_KEYS}
-            st = dc_replace(st, cache=dict(st.cache, **pool),
-                            dcache=dict(st.dcache, **dpool))
-        else:
-            cur.row_cache, cur.row_dcache = cache, dcache
+            cur.prev_feat = fused[:, -1]
+            cur.logits_last = logits_last
+            if self.paged:
+                # the pools were written in place (batch-1 view); rebind
+                # them into the batched state so interleaved decode steps
+                # see the chunk, and keep only the per-row keys in the
+                # cursor
+                cur.row_cache = {n: v for n, v in cache.items()
+                                 if n not in kvc.PAGED_POOL_KEYS}
+                cur.row_dcache = {n: v for n, v in dcache.items()
+                                  if n not in kvc.DRAFT_POOL_KEYS}
+                pool = {n: cache[n] for n in kvc.PAGED_POOL_KEYS}
+                dpool = {n: dcache[n] for n in kvc.DRAFT_POOL_KEYS}
+                st = dc_replace(st, cache=dict(st.cache, **pool),
+                                dcache=dict(st.dcache, **dpool))
+            else:
+                cur.row_cache, cur.row_dcache = cache, dcache
+            feats = self._block_feats(cur, off, end, fused, 0)
+        with span(self.stats, "engine.prefill.wait", request_id=rid):
+            feats = jax.device_get(feats)
         # registration runs AFTER the row-cache rebind: a hash-equal
         # dedupe repoints the cursor's page table, and that edit must
         # land on the rebound row cache, not be clobbered by it
-        self._register_blocks(cur, off, end, fused[0])
+        with span(self.stats, "engine.prefill.register", request_id=rid):
+            self._register_blocks(cur, off, feats)
         cur.off = end
         return st, end - off
 
-    def _register_blocks(self, cur: PrefillCursor, off: int, end: int,
-                         fused_row) -> None:
-        """Register the prompt blocks completed by the chunk
-        ``[off, end)`` into the prefix cache, re-stamping the whole
-        chain with one LRU tick (a parent may never be older than its
-        children, or eviction could drop a chain head and orphan the
-        tail).  ``fused_row`` is the chunk's [T, 3d] fused features for
-        this cursor's row — block-boundary columns are harvested as the
-        entries' draft boot features.
+    def _block_feats(self, cur: PrefillCursor, off: int, end: int,
+                     fused, row: int) -> List:
+        """The draft boot features of the prompt blocks the chunk
+        ``[off, end)`` completes and the prefix cache registers (none
+        without a cache): the block-boundary columns of row ``row`` of
+        the chunk's [K, T, 3d] fused features, still on the device."""
+        if not (self.paged and self._prefix is not None and cur.n_full):
+            return []
+        bs = self.spec.block_size
+        lo, hi = off // bs, min(end // bs, cur.n_full)
+        if hi <= lo:
+            return []
+        fused_row = fused[row]
+        return [fused_row[(j + 1) * bs - 1 - off] for j in range(lo, hi)]
+
+    def _register_blocks(self, cur: PrefillCursor, off: int,
+                         feats: List[np.ndarray]) -> None:
+        """Register the prompt blocks completed by the chunk starting at
+        ``off`` into the prefix cache, re-stamping the whole chain with
+        one LRU tick (a parent may never be older than its children, or
+        eviction could drop a chain head and orphan the tail).
+        ``feats`` are the host copies of ``_block_feats``: one boot
+        feature per block, in block order.
 
         A block some concurrent admission already registered under the
         same chain key is *deduplicated* instead: this cursor's freshly
         computed page is collapsed onto the cached entry's page (see
         ``_dedupe_block``), so same-tick cold admissions of a shared
         prompt converge on ONE physical copy."""
-        if not (self.paged and self._prefix is not None and cur.n_full):
+        if not feats:
             return
         bs = self.spec.block_size
-        lo, hi = off // bs, min(end // bs, cur.n_full)
-        if hi <= lo:
-            return
         tick = self._prefix.new_tick()
         for e in cur.chain_entries:
             e.tick = tick
-        for j in range(lo, hi):
-            p = (j + 1) * bs - 1
+        for j, feat in enumerate(feats, off // bs):
             e = self._prefix.insert(
                 cur.chain_keys[j], j, int(cur.pt_host[j]),
-                int(cur.dpt_host[j]), np.asarray(fused_row[p - off]),
+                int(cur.dpt_host[j]), np.asarray(feat),
                 self._page_alloc, self._draft_alloc, tick=tick,
                 tokens=cur.prompt[j * bs:(j + 1) * bs],
                 parent=(cur.chain_keys[j - 1] if j > 0
@@ -1971,6 +2010,27 @@ class SpecPVEngine:
         assert cursors, "no open prefill cursor"
         assert all(c.extra is None for c in cursors), \
             "fused prefill cannot batch per-request `extra` conditioning"
+        rids = ",".join(c.request_id for c in cursors)
+        with span(self.stats, "engine.prefill.dispatch", request_id=rids):
+            st, offs, ends, feats = self._prefill_dispatch(st, cursors)
+        with span(self.stats, "engine.prefill.wait", request_id=rids):
+            feats = jax.device_get(feats)
+        # cursor order = admission (FIFO) order: cursor B completing a
+        # block cursor A just registered this same tick collapses onto
+        # A's page here
+        with span(self.stats, "engine.prefill.register", request_id=rids):
+            for cur, off, f in zip(cursors, offs, feats):
+                self._register_blocks(cur, off, f)
+        for cur, end in zip(cursors, ends):
+            cur.off = end
+        return st, sum(ends) - sum(offs)
+
+    def _prefill_dispatch(self, st: EngineState,
+                          cursors: Sequence[PrefillCursor]):
+        """The dispatching half of ``prefill_step_fused``: one fused
+        chunk for every cursor, each cursor's row keys rebound, and the
+        pools rebound into ``st``.  Returns (state, chunk offsets, chunk
+        ends, each cursor's ``_block_feats`` still on the device)."""
         k = len(cursors)
         offs = [c.off for c in cursors]
         ends = [min(len(c.prompt), (c.off // c.chunk + 1) * c.chunk)
@@ -2009,7 +2069,7 @@ class SpecPVEngine:
                     jnp.asarray(toks), t_valid, prev_feat)
         self.prefill_dispatches += 1
 
-        total = 0
+        feats = []
         for i, cur in enumerate(cursors):
             cur.prev_feat = feat_last[i: i + 1]
             cur.logits_last = logits[i: i + 1]
@@ -2025,18 +2085,13 @@ class SpecPVEngine:
             else:
                 cur.row_cache = cache[i]
                 cur.row_dcache = dcache[i]
-            # cursor order = admission (FIFO) order: cursor B completing
-            # a block cursor A just registered this same tick collapses
-            # onto A's page here
-            self._register_blocks(cur, offs[i], ends[i], fused[i])
-            cur.off = ends[i]
-            total += ends[i] - offs[i]
+            feats.append(self._block_feats(cur, offs[i], ends[i], fused, i))
         if self.paged:
             pool = {n: cache[n] for n in kvc.PAGED_POOL_KEYS}
             dpool = {n: dcache[n] for n in kvc.DRAFT_POOL_KEYS}
             st = dc_replace(st, cache=dict(st.cache, **pool),
                             dcache=dict(st.dcache, **dpool))
-        return st, total
+        return st, offs, ends, feats
 
     def prefill_finalize_slot(self, st: EngineState, cur: PrefillCursor
                               ) -> Tuple[EngineState, int]:
@@ -2049,35 +2104,41 @@ class SpecPVEngine:
         entirely.  Returns (state, first token).  Consumes `st` —
         callers must rebind."""
         assert cur.done, "prefill cursor still has chunks to run"
-        if cur.boot_token is not None:
-            # tail-entry boots are greedy-only (gated at begin), so the
-            # stream key is all the sampling state the slot needs — and
-            # it matches a full prefill of the same (prompt, seed) exactly
-            sub = self._boot_state_from_token(
-                cur.row_cache, cur.row_dcache,
-                jnp.full((1,), cur.boot_token, jnp.int32),
-                cur.prev_feat, len(cur.prompt),
-                keys=self._seed_keys(cur.seed, 1)[1],
-                temps=jnp.full((1,), cur.temperature, jnp.float32))
-        else:
-            st = self._register_tail(st, cur)
-            sub = self._boot_state(cur.row_cache, cur.row_dcache,
-                                   cur.logits_last, cur.prev_feat,
-                                   len(cur.prompt),
-                                   temperature=cur.temperature,
-                                   seed=cur.seed)
-        self._pkv_active_rows[cur.slot] = False
-        self._slot_temp[cur.slot] = cur.temperature
-        self._slot_chain[cur.slot] = (cur.draft == "chain")
-        st = self._write_slot(st, sub, jnp.int32(cur.slot))
-        return st, int(np.asarray(sub.pending[0, 0]))
+        with span(self.stats, "engine.prefill.finalize",
+                  request_id=cur.request_id):
+            if cur.boot_token is not None:
+                # tail-entry boots are greedy-only (gated at begin), so the
+                # stream key is all the sampling state the slot needs — and
+                # it matches a full prefill of the same (prompt, seed) exactly
+                sub = self._boot_state_from_token(
+                    cur.row_cache, cur.row_dcache,
+                    jnp.full((1,), cur.boot_token, jnp.int32),
+                    cur.prev_feat, len(cur.prompt),
+                    keys=self._seed_keys(cur.seed, 1)[1],
+                    temps=jnp.full((1,), cur.temperature, jnp.float32))
+            else:
+                st = self._register_tail(st, cur)
+                sub = self._boot_state(cur.row_cache, cur.row_dcache,
+                                       cur.logits_last, cur.prev_feat,
+                                       len(cur.prompt),
+                                       temperature=cur.temperature,
+                                       seed=cur.seed)
+            self._pkv_active_rows[cur.slot] = False
+            self._slot_temp[cur.slot] = cur.temperature
+            self._slot_chain[cur.slot] = (cur.draft == "chain")
+            st = self._write_slot(st, sub, jnp.int32(cur.slot))
+            with span(self.stats, "engine.prefill.finalize.wait",
+                      request_id=cur.request_id):
+                first = int(jax.device_get(sub.pending[0, 0]))
+        return st, first
 
     def prefill_into_slot(self, st: EngineState, slot: int,
                           prompt: np.ndarray, chunk: int = 256,
                           extra: Optional[Dict] = None,
                           max_new_tokens: Optional[int] = None,
                           temperature: Optional[float] = None,
-                          seed: int = 0, draft: str = "tree"
+                          seed: int = 0, draft: str = "tree",
+                          request_id: str = ""
                           ) -> Tuple[EngineState, int]:
         """Admit a request in one blocking call: chunked batch-1 prefill,
         then scatter the sub-state into batch row `slot`.  Returns
@@ -2091,7 +2152,8 @@ class SpecPVEngine:
                                           extra=extra,
                                           max_new_tokens=max_new_tokens,
                                           temperature=temperature,
-                                          seed=seed, draft=draft)
+                                          seed=seed, draft=draft,
+                                          request_id=request_id)
         while not cur.done:
             st, _ = self.prefill_step_into_slot(st, cur)
         return self.prefill_finalize_slot(st, cur)
@@ -2240,8 +2302,8 @@ class SpecPVEngine:
         normalises them).  This is the fused tick's one host-side
         decision; the vector then rides through the jitted step as an
         operand."""
-        pl = np.asarray(st.pending_len)
-        sl = np.asarray(st.seq_len)
+        with span(self.stats, "engine.modes.wait"):
+            pl, sl = jax.device_get((st.pending_len, st.seq_len))
         out = np.full((self.batch,), MODE_FULL, np.int8)
         for i in np.nonzero(rows)[0]:
             out[i] = MODE_IDS[self.mode_for(
@@ -2273,64 +2335,79 @@ class SpecPVEngine:
         assert self.is_attn, \
             "fused steps drive the attention automaton; state archs " \
             "use step(mode='state')"
-        rows = np.asarray(rows, bool)
-        modes = np.asarray(modes, np.int8)
-        active_modes = modes[rows]
-        assert active_modes.size, "step_fused needs at least one live row"
-        has_refresh = bool(np.any(active_modes == MODE_REFRESH))
-        has_full = has_refresh or bool(np.any(active_modes == MODE_FULL))
-        has_partial = bool(np.any(active_modes == MODE_PARTIAL))
-        # sampling/chain flags from the host mirrors: like the mode mix,
-        # they pick which masked branches exist — the per-row behaviour
-        # rides on state operands (temps/keys) and the is_chain vector.
-        # Chain masking is the identity when the tree IS a chain.
-        has_sampled = bool(np.any(self._slot_temp[rows] > 0.0))
-        has_chain = bool(self._tree_branching
-                         and np.any(self._slot_chain[rows]))
-        # inactive rows' compute is discarded by the in-jit row merge;
-        # normalise their mode entries to one the variant implements so
-        # the per-row selects never see an unrepresented mode
-        modes = np.where(rows, modes, active_modes[0]).astype(np.int8)
-        st = self.prepare_cow(st, rows)
-        if self._tier is not None:
-            # seat hosted pages before any full-cache read (prefetch
-            # hits land free; early refreshes pay a synchronous copy)
-            st = self._tier_promote_rows(st, rows, modes)
-        fn = self._fused_fn(has_full, has_partial, has_refresh,
-                            has_sampled, has_chain)
-        with self._on_mesh():
-            st, (toks, counts, acc) = fn(self.params, self.dparams, st,
-                                         jnp.asarray(rows),
-                                         jnp.asarray(modes),
-                                         jnp.asarray(self._slot_chain))
-        self.dispatches += 1
-        self._pkv_active_rows |= rows & (modes == MODE_REFRESH)
-        if self.zero_copy and has_refresh:
-            # pin the pages the refresh just routed — BEFORE the tier
-            # epilogue, so demotion excludes them.  pin_slot_pages takes
-            # the new references before dropping the previous refresh's,
-            # so a page kept across refreshes never transiently frees.
-            al = self._page_alloc
-            pbi_host = np.asarray(st.pkv_blocks)
-            for i in np.nonzero(rows & (modes == MODE_REFRESH))[0]:
-                i = int(i)
-                blocks = np.unique(pbi_host[i][pbi_host[i] >= 0])
-                nb = al.count(i)
-                pages = [al.page_at(i, int(j)) for j in blocks if j < nb]
-                if pages:
-                    al.pin_slot_pages(i, pages)
-        self._record_traffic_rows(modes, st, rows)
-        if self._tier is not None:
-            # refresh epilogue: committed blocks go cold until the next
-            # refresh — demote them; near-refresh partials prefetch
-            st = self._tier_epilogue(st, rows, modes)
-        counts = np.where(rows, np.asarray(counts), 0)
-        names = sorted({MODE_NAMES[int(m)] for m in active_modes})
-        return st, StepOutput(tokens=np.asarray(toks), counts=counts,
-                              accept_len=np.where(rows, np.asarray(acc), 0),
-                              mode=(names[0] if len(names) == 1
-                                    else "fused"),
-                              modes=modes)
+        with span(self.stats, "engine.step.prepare"):
+            rows = np.asarray(rows, bool)
+            modes = np.asarray(modes, np.int8)
+            active_modes = modes[rows]
+            assert active_modes.size, "step_fused needs at least one live row"
+            has_refresh = bool(np.any(active_modes == MODE_REFRESH))
+            has_full = has_refresh or bool(np.any(active_modes == MODE_FULL))
+            has_partial = bool(np.any(active_modes == MODE_PARTIAL))
+            # sampling/chain flags from the host mirrors: like the mode
+            # mix, they pick which masked branches exist — the per-row
+            # behaviour rides on state operands (temps/keys) and the
+            # is_chain vector.  Chain masking is the identity when the
+            # tree IS a chain.
+            has_sampled = bool(np.any(self._slot_temp[rows] > 0.0))
+            has_chain = bool(self._tree_branching
+                             and np.any(self._slot_chain[rows]))
+            # inactive rows' compute is discarded by the in-jit row
+            # merge; normalise their mode entries to one the variant
+            # implements so the per-row selects never see an
+            # unrepresented mode
+            modes = np.where(rows, modes, active_modes[0]).astype(np.int8)
+            st = self.prepare_cow(st, rows)
+            if self._tier is not None:
+                # seat hosted pages before any full-cache read (prefetch
+                # hits land free; early refreshes pay a synchronous copy)
+                st = self._tier_promote_rows(st, rows, modes)
+            fn = self._fused_fn(has_full, has_partial, has_refresh,
+                                has_sampled, has_chain)
+        with span(self.stats, "engine.step.dispatch"):
+            with self._on_mesh():
+                st, (toks, counts, acc) = fn(self.params, self.dparams, st,
+                                             jnp.asarray(rows),
+                                             jnp.asarray(modes),
+                                             jnp.asarray(self._slot_chain))
+            self.dispatches += 1
+        pin = self.zero_copy and has_refresh
+        # everything the host reads of the step, in one fetch: tokens,
+        # counts, accept lengths, the lengths the traffic bill reads and,
+        # after a zero-copy refresh, the routed blocks to pin
+        with span(self.stats, "engine.step.wait"):
+            toks, counts, acc, seq_len, pbi_host = jax.device_get(
+                (toks, counts, acc, st.seq_len,
+                 st.pkv_blocks if pin else None))
+        with span(self.stats, "engine.step.post"):
+            self._pkv_active_rows |= rows & (modes == MODE_REFRESH)
+            if pin:
+                # pin the pages the refresh just routed — BEFORE the tier
+                # epilogue, so demotion excludes them.  pin_slot_pages
+                # takes the new references before dropping the previous
+                # refresh's, so a page kept across refreshes never
+                # transiently frees.
+                al = self._page_alloc
+                for i in np.nonzero(rows & (modes == MODE_REFRESH))[0]:
+                    i = int(i)
+                    blocks = np.unique(pbi_host[i][pbi_host[i] >= 0])
+                    nb = al.count(i)
+                    pages = [al.page_at(i, int(j)) for j in blocks
+                             if j < nb]
+                    if pages:
+                        al.pin_slot_pages(i, pages)
+            self._record_traffic_rows(modes, seq_len, rows)
+            if self._tier is not None:
+                # refresh epilogue: committed blocks go cold until the
+                # next refresh — demote them; near-refresh partials
+                # prefetch
+                st = self._tier_epilogue(st, rows, modes)
+            names = sorted({MODE_NAMES[int(m)] for m in active_modes})
+            out = StepOutput(tokens=toks, counts=np.where(rows, counts, 0),
+                             accept_len=np.where(rows, acc, 0),
+                             mode=(names[0] if len(names) == 1
+                                   else "fused"),
+                             modes=modes)
+        return st, out
 
     def step(self, st: EngineState, mode: str) -> Tuple[EngineState,
                                                         StepOutput]:
@@ -2371,18 +2448,19 @@ class SpecPVEngine:
         return self.step_fused(
             st, rows, np.full((self.batch,), MODE_IDS[mode], np.int8))
 
-    def _record_traffic_rows(self, modes: np.ndarray, st: EngineState,
+    def _record_traffic_rows(self, modes: np.ndarray, seq_len: np.ndarray,
                              rows: np.ndarray) -> None:
         """Per-row mode attribution: one traffic record per distinct
         mode actually stepped, each billed only for its own rows."""
         for mid in (MODE_FULL, MODE_REFRESH, MODE_PARTIAL):
             sub = rows & (modes == mid)
             if sub.any():
-                self._record_traffic(MODE_NAMES[mid], st, sub)
+                self._record_traffic(MODE_NAMES[mid], seq_len, sub)
 
-    def _record_traffic(self, mode: str, st: EngineState,
+    def _record_traffic(self, mode: str, seq_len: np.ndarray,
                         rows: Optional[np.ndarray] = None):
-        """rows: which batch rows actually stepped (masked continuous
+        """seq_len: [B] host copy of the stepped state's lengths.
+        rows: which batch rows actually stepped (masked continuous
         steps); None = the whole batch (lock-step path).
 
         Full-cache bytes are billed per row and *summed* — rows step at
@@ -2398,7 +2476,6 @@ class SpecPVEngine:
         from repro.models.dense import attn_layer_count
         l_attn = attn_layer_count(cfg.layer_kinds())
         itemsize = 2 if cfg.dtype == "bfloat16" else 4
-        seq_len = np.asarray(st.seq_len)
         if rows is None:
             nrows, seq_sum = self.batch, int(np.sum(seq_len))
         else:

@@ -13,6 +13,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.names import named
+
 
 def _kernel(len_ref, k_ref, kmax_ref, kmin_ref, *, block_size: int):
     i = pl.program_id(0)
@@ -30,7 +32,9 @@ def _kernel(len_ref, k_ref, kmax_ref, kmin_ref, *, block_size: int):
 def block_summary_pallas(k, length, block_size: int, *,
                          interpret: bool = True):
     """k: [S, Hk, Dh]; length: scalar int32.  Returns (kmax, kmin):
-    [NB, Hk, Dh] fp32."""
+    [NB, Hk, Dh] fp32.  The kernel is named ``specpv_block_summary`` in
+    compiled programs (``kernels/names.py``); under ``vmap`` it runs once
+    per row."""
     s, hk, dh = k.shape
     nb = s // block_size
     k = k[: nb * block_size]
@@ -44,8 +48,9 @@ def block_summary_pallas(k, length, block_size: int, *,
     )
     out_shape = [jax.ShapeDtypeStruct((nb, hk, dh), jnp.float32),
                  jax.ShapeDtypeStruct((nb, hk, dh), jnp.float32)]
-    fn = pl.pallas_call(
+    fn = named("specpv_block_summary", pl.pallas_call(
         functools.partial(_kernel, block_size=block_size),
-        grid_spec=grid_spec, out_shape=out_shape, interpret=interpret)
+        grid_spec=grid_spec, out_shape=out_shape, interpret=interpret),
+        rows=True)
     length = jnp.asarray(length, jnp.int32).reshape(1)
     return tuple(fn(length, k))

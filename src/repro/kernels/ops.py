@@ -4,6 +4,13 @@ an automatic interpret-mode fallback on non-TPU backends.
 On CPU (this container) the kernels execute in ``interpret=True`` mode —
 the kernel body runs step-by-step exactly as the TPU grid would, which is
 what the correctness sweeps in tests/test_kernels.py validate.
+
+Each call site names its kernel in the compiled program and the
+profiler trace (``kernels/names.py``): ``specpv_attn_full`` (paged
+full / refresh verification), ``specpv_attn_partial`` (zero-copy
+partial verification), ``specpv_attn_prefill`` (paged prefill),
+``specpv_attn_blocks`` (block-sparse over a contiguous cache),
+``specpv_retrieval_score`` and ``specpv_block_summary``.
 """
 from __future__ import annotations
 
@@ -75,13 +82,14 @@ def retrieval_scores(q, kmax, kmin, q_weight, use_pallas: bool = True):
 
 
 def _block_attention(q, k_blocks, v_blocks, block_idx, block_valid_len,
-                     q_pos=None, *, use_pallas: bool):
-    """One row: the block-attention kernel, or its ``ref`` oracle behind
+                     q_pos=None, *, use_pallas: bool, name: str):
+    """One row: the block-attention kernel named ``name`` (each call
+    site its own, ``kernels/names.py``), or its ``ref`` oracle behind
     the kernel's signature (``sparse_attention.block_attention_pallas``)."""
     if use_pallas:
         return block_attention_pallas(q, k_blocks, v_blocks, block_idx,
                                       block_valid_len, q_pos,
-                                      interpret=_interpret())
+                                      interpret=_interpret(), name=name)
     nb, bs, hk, dh = k_blocks.shape
     kf = k_blocks.reshape(nb * bs, hk, dh)
     vf = v_blocks.reshape(nb * bs, hk, dh)
@@ -106,7 +114,8 @@ def sparse_verify_attention(q, k_cache, v_cache, block_idx, block_valid_len,
     nb = s // block_size
     kb = k_cache[:, : nb * block_size].reshape(b, nb, block_size, hk, dh)
     vb = v_cache[:, : nb * block_size].reshape(b, nb, block_size, hk, dh)
-    fn = functools.partial(_block_attention, use_pallas=use_pallas)
+    fn = functools.partial(_block_attention, use_pallas=use_pallas,
+                           name="specpv_attn_blocks")
     return jax.vmap(fn)(q, kb, vb, block_idx, block_valid_len)
 
 
@@ -143,7 +152,8 @@ def paged_verify_attention(q, pool_k, pool_v, page_table, length,
     # ragged routing: empty blocks stream the null page (their valid
     # length is 0, so the masked tile contributes nothing either way)
     routed = jnp.where(vlen > 0, page_table, 0)
-    fn = jax.vmap(functools.partial(_block_attention, use_pallas=use_pallas),
+    fn = jax.vmap(functools.partial(_block_attention, use_pallas=use_pallas,
+                                    name="specpv_attn_full"),
                   in_axes=(0, None, None, 0, 0))
     return _per_shard(fn, (q, pool_k, pool_v,
                          routed[:, None].astype(jnp.int32),
@@ -174,7 +184,8 @@ def routed_partial_attention(q, pool_k, pool_v, block_idx, block_valid_len,
     Returns (m [B, H, T], l [B, H, T], acc [B, H, T, Dh]) fp32 —
     combinable with the tail-buffer and tree self-segments via
     ``models.common.merge_attn_partials``/``combine_attn_parts``."""
-    fn = jax.vmap(functools.partial(_block_attention, use_pallas=use_pallas),
+    fn = jax.vmap(functools.partial(_block_attention, use_pallas=use_pallas,
+                                    name="specpv_attn_partial"),
                   in_axes=(0, None, None, 0, 0))
     return _per_shard(fn, (q, pool_k, pool_v, block_idx.astype(jnp.int32),
                          block_valid_len.astype(jnp.int32)),
@@ -213,7 +224,8 @@ def paged_prefill_attention(q, pool_k, pool_v, page_table, length, t_valid,
     vlen = jnp.clip(end[:, None] - jnp.arange(nb)[None] * bs, 0, bs)
     routed = jnp.where(vlen > 0, page_table, 0)
     q_pos = length.astype(jnp.int32)[:, None] + jnp.arange(t)[None]
-    fn = jax.vmap(functools.partial(_block_attention, use_pallas=use_pallas),
+    fn = jax.vmap(functools.partial(_block_attention, use_pallas=use_pallas,
+                                    name="specpv_attn_prefill"),
                   in_axes=(0, None, None, 0, 0, 0))
     m, l, acc = _per_shard(fn, (q, pool_k, pool_v,
                               routed[:, None].astype(jnp.int32),

@@ -14,6 +14,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels.names import named
+
 
 def _kernel(q_ref, kmax_ref, kmin_ref, qw_ref, out_ref):
     q = q_ref[0].astype(jnp.float32)                      # [R, Dh]
@@ -34,14 +36,16 @@ def _kernel(q_ref, kmax_ref, kmin_ref, qw_ref, out_ref):
 def retrieval_score_pallas(q, kmax, kmin, q_weight, *,
                            interpret: bool = True):
     """q: [T, H, Dh]; kmax/kmin: [NB, Hk, Dh]; q_weight: [T].
-    Returns scores [Hk, NB] fp32 (paper score mode, mean reduction)."""
+    Returns scores [Hk, NB] fp32 (paper score mode, mean reduction).
+    The kernel is named ``specpv_retrieval_score`` in compiled programs
+    (``kernels/names.py``)."""
     t, h, dh = q.shape
     nb, hk, _ = kmax.shape
     rep = h // hk
     r = rep * t
     qg = q.reshape(t, hk, rep, dh).transpose(1, 2, 0, 3).reshape(hk, r, dh)
     qw = jnp.tile(q_weight.astype(jnp.float32), rep)[:, None]   # [R, 1]
-    fn = pl.pallas_call(
+    fn = named("specpv_retrieval_score", pl.pallas_call(
         _kernel,
         grid=(hk,),
         in_specs=[
@@ -52,6 +56,6 @@ def retrieval_score_pallas(q, kmax, kmin, q_weight, *,
         ],
         out_specs=pl.BlockSpec((1, 1, nb), lambda i: (i, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((hk, 1, nb), jnp.float32),
-        interpret=interpret)
+        interpret=interpret))
     out = fn(qg, kmax.reshape(nb, hk * dh), kmin.reshape(nb, hk * dh), qw)
     return out.reshape(hk, nb)

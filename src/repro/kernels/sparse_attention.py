@@ -35,6 +35,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.names import named
+
 NEG = -1e30
 
 
@@ -82,7 +84,8 @@ def _kernel(idx_ref, vlen_ref, *refs, block_size: int, nblk: int,
 
 def block_attention_pallas(q, k_blocks, v_blocks, block_idx,
                            block_valid_len, q_pos: Optional[jax.Array] = None,
-                           *, interpret: bool = True):
+                           *, interpret: bool = True,
+                           name: str = "specpv_attn_blocks"):
     """Softmax partials of ``q`` over routed KV blocks.
 
     q: [T, H, Dh]; k_blocks/v_blocks: [NB, bs, Hk, Dh] (a page pool, or a
@@ -91,7 +94,9 @@ def block_attention_pallas(q, k_blocks, v_blocks, block_idx,
     reads the same blocks) — grid step j reads block ``block_idx[h, j]``
     and keeps its first ``block_valid_len[h, j]`` keys; q_pos: [T] int32
     absolute query positions, given for causal (prefill) attention where
-    grid step j is logical block j.
+    grid step j is logical block j.  ``name`` names the kernel in the
+    compiled program and the profiler trace (``kernels/names.py``); under
+    ``vmap`` it runs once per row.
 
     Returns softmax partials (m [H, T], l [H, T], acc [H, T, Dh]) fp32."""
     t, h, dh = q.shape
@@ -103,8 +108,9 @@ def block_attention_pallas(q, k_blocks, v_blocks, block_idx,
     scale = 1.0 / math.sqrt(dh)
     qg = (q.reshape(t, hk, rep, dh).transpose(1, 2, 0, 3)
           * scale).reshape(hk, r, dh)                      # [Hk, R, Dh]
-    kf = k_blocks.reshape(nb, bs, hk * dh)
-    vf = v_blocks.reshape(nb, bs, hk * dh)
+    with jax.named_scope("relayout"):      # a copy on TPU, not a view
+        kf = k_blocks.reshape(nb, bs, hk * dh)
+        vf = v_blocks.reshape(nb, bs, hk * dh)
 
     def kv_map(hh, jj, idx, *_):
         return idx[hh if hi > 1 else 0, jj], 0, hh
@@ -133,10 +139,11 @@ def block_attention_pallas(q, k_blocks, v_blocks, block_idx,
     out_shape = [jax.ShapeDtypeStruct((hk, r, 1), jnp.float32),
                  jax.ShapeDtypeStruct((hk, r, 1), jnp.float32),
                  jax.ShapeDtypeStruct((hk, r, dh), jnp.float32)]
-    fn = pl.pallas_call(
+    fn = named(name, pl.pallas_call(
         functools.partial(_kernel, block_size=bs, nblk=nblk,
                           per_head=hi > 1, causal=causal),
-        grid_spec=grid_spec, out_shape=out_shape, interpret=interpret)
+        grid_spec=grid_spec, out_shape=out_shape, interpret=interpret),
+        rows=True)
     idx = jnp.clip(block_idx.astype(jnp.int32), 0, nb - 1)
     m, l, acc = fn(idx, block_valid_len.astype(jnp.int32), *operands)
     return (m.reshape(h, t), l.reshape(h, t), acc.reshape(h, t, dh))

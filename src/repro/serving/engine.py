@@ -246,7 +246,7 @@ class ServingEngine:
                      "page_stalls", "prefix_evictions", "prefill_tokens",
                      "prefill_dispatches", "tier_defers") \
                     or k.startswith(("mode_rows_", "ticks_modes_",
-                                     "tick_wall_", "ticks_wall_")):
+                                     "span_s.", "span_n.")):
                 self.stats[k] += sched.stats.pop(k)
         # sharded engines: the headline residency number is the worst
         # single host, not the pool total (a max across hosts AND runs)

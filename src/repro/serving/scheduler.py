@@ -80,6 +80,7 @@ import numpy as np
 from repro.core.engine import (MODE_FULL, MODE_NAMES, MODE_PARTIAL,
                                MODE_REFRESH, PrefillCursor, SpecPVEngine)
 from repro.serving.request import Request, RequestOutput, RequestPhase
+from repro.utils.spans import add as add_span, span
 
 
 def trim_output(tokens: List[int], max_new: int, eos_id: int) -> np.ndarray:
@@ -181,12 +182,9 @@ class ContinuousScheduler:
         self.trace: List[tuple] = []        # (event, request_id, slot)
         self.step_log: List[tuple] = []     # (t, request_id, n_tokens)
         self.stats = defaultdict(float)
-        # refresh-cost observability: raw per-tick decode wall times by
-        # tick class ("refresh" when any row refreshed, "partial" when
-        # every row was partial, else "full"/"mixed") — percentile
-        # source for bench_serving; the sums/counts mirror into stats
-        # as tick_wall_<class> / ticks_wall_<class>
-        self.tick_wall: Dict[str, List[float]] = defaultdict(list)
+        # the engine's spans land in this same mapping
+        # (``repro.utils.spans``; docs/serving.md#observability)
+        engine.stats = self.stats
 
     # ------------------------------------------------------------------
     def submit(self, req: Request) -> None:
@@ -335,23 +333,23 @@ class ContinuousScheduler:
             req.phase = RequestPhase.PREFILLING
             slot = _Slot(req=req, admit_s=now, seq=self._seq)
             self._seq += 1
-            if self.prefill_budget is None:
-                # blocking admission: the whole prompt prefills now
-                self.st, first = self.engine.prefill_into_slot(
-                    self.st, i, req.prompt, chunk=self.prefill_chunk,
-                    max_new_tokens=req.max_new_tokens,
-                    temperature=req.temperature, seed=req.seed,
-                    draft=req.draft)
-                req.phase = RequestPhase.DECODING
-                slot.append([first])
-            else:
-                # interleaved admission: open a resumable cursor; chunks
-                # run inside _pump_prefill under the per-tick budget
-                self.st, slot.cursor = self.engine.prefill_begin_slot(
-                    self.st, i, req.prompt, chunk=self.prefill_chunk,
-                    max_new_tokens=req.max_new_tokens,
-                    temperature=req.temperature, seed=req.seed,
-                    draft=req.draft)
+            knobs = dict(chunk=self.prefill_chunk,
+                         max_new_tokens=req.max_new_tokens,
+                         temperature=req.temperature, seed=req.seed,
+                         draft=req.draft, request_id=req.request_id)
+            with span(self.stats, "sched.admit.request",
+                      request_id=req.request_id):
+                if self.prefill_budget is None:
+                    # blocking admission: the whole prompt prefills now
+                    self.st, first = self.engine.prefill_into_slot(
+                        self.st, i, req.prompt, **knobs)
+                    req.phase = RequestPhase.DECODING
+                    slot.append([first])
+                else:
+                    # interleaved admission: open a resumable cursor;
+                    # chunks run inside _pump_prefill under the budget
+                    self.st, slot.cursor = self.engine.prefill_begin_slot(
+                        self.st, i, req.prompt, **knobs)
             self._dirty.discard(i)
             self.slots[i] = slot
             self.stats["admissions"] += 1
@@ -462,7 +460,13 @@ class ContinuousScheduler:
     def tick(self) -> bool:
         """One scheduler round: evict, admit, pump prefill chunks (when
         interleaving), step the decoding slots.  Returns True when any
-        work ran — a decode step or prefill progress (False = idle)."""
+        work ran — a decode step or prefill progress (False = idle).
+        Each phase is a span (``sched.*``, docs/serving.md#observability)
+        inside the tick's own ``sched.tick``."""
+        with span(self.stats, "sched.tick"):
+            return self._tick()
+
+    def _tick(self) -> bool:
         # evictions: cancellation first, then natural completion (a slot
         # can satisfy its stop condition during the previous tick's step),
         # then deadline misses — an in-flight request past its deadline_s
@@ -471,17 +475,23 @@ class ContinuousScheduler:
         # cursor (pages released via _evict; registered prefix blocks
         # stay cached) and the request reports whatever it has (nothing).
         now = self.clock()
-        for i, s in enumerate(self.slots):
-            if s is None:
-                continue
-            if s.req.cancelled:
-                self._evict(i, "cancelled")
-            elif s.done_reason():
-                self._evict(i, s.done_reason())
-            elif s.req.deadline_s is not None and s.req.deadline_s < now:
-                self._evict(i, "deadline")
-        self._admit()
-        prefilled = self._pump_prefill() if self.prefill_budget else 0
+        with span(self.stats, "sched.evict"):
+            for i, s in enumerate(self.slots):
+                if s is None:
+                    continue
+                if s.req.cancelled:
+                    self._evict(i, "cancelled")
+                elif s.done_reason():
+                    self._evict(i, s.done_reason())
+                elif (s.req.deadline_s is not None
+                      and s.req.deadline_s < now):
+                    self._evict(i, "deadline")
+        with span(self.stats, "sched.admit"):
+            self._admit()
+        prefilled = 0
+        if self.prefill_budget:
+            with span(self.stats, "sched.prefill"):
+                prefilled = self._pump_prefill()
 
         # decode: slots mid-prefill have no automaton state yet and sit
         # this phase out (their device rows are neutral — masked steps
@@ -492,14 +502,16 @@ class ContinuousScheduler:
                                         float(np.sum(active)))
         if not active.any():
             return prefilled > 0
-        modes = self.engine.modes_for_rows(self.st, active)
-        # tiered engines: refresh rows whose promotion cannot seat this
-        # tick sit it out (pages return as other slots re-demote).  Only
-        # force the all-deferred fallback when nothing else progressed:
-        # a pumping prefill cursor holds its full page bill until its
-        # first refresh, and its completion is what unblocks the pool.
-        active, deferred = self.engine.tier_ready_rows(
-            active, modes, force=(prefilled == 0))
+        with span(self.stats, "sched.modes"):
+            modes = self.engine.modes_for_rows(self.st, active)
+            # tiered engines: refresh rows whose promotion cannot seat
+            # this tick sit it out (pages return as other slots
+            # re-demote).  Only force the all-deferred fallback when
+            # nothing else progressed: a pumping prefill cursor holds its
+            # full page bill until its first refresh, and its completion
+            # is what unblocks the pool.
+            active, deferred = self.engine.tier_ready_rows(
+                active, modes, force=(prefilled == 0))
         if deferred:
             self.stats["tier_defers"] += deferred
         if not active.any():
@@ -509,27 +521,24 @@ class ContinuousScheduler:
         for mid in distinct:
             self.stats["mode_rows_" + MODE_NAMES[mid]] += int(
                 np.sum(active & (modes == mid)))
-        t_dec = self.clock()
-        if self.fused:
-            # the whole mode mix in ONE jitted dispatch
-            self.st, so = self.engine.step_fused(self.st, active, modes)
-            self.stats["steps"] += 1
-            self._harvest(so, active)
-        else:
-            # grouped A/B path: one masked dispatch per distinct mode
-            for mid in distinct:
-                mask = active & (modes == mid)
-                self.st, so = self.engine.step_rows(self.st,
-                                                    MODE_NAMES[mid], mask)
+        with span(self.stats, "sched.decode") as dec:
+            if self.fused:
+                # the whole mode mix in ONE jitted dispatch
+                self.st, so = self.engine.step_fused(self.st, active, modes)
                 self.stats["steps"] += 1
-                self._harvest(so, mask)
-        # per-tick decode wall time by tick class (the host wrapper
-        # materialises the step's tokens, so the dispatch has drained)
-        cls = self._tick_class(modes, active)
-        dt = self.clock() - t_dec
-        self.tick_wall[cls].append(dt)
-        self.stats["tick_wall_" + cls] += dt
-        self.stats["ticks_wall_" + cls] += 1
+                self._harvest(so, active)
+            else:
+                # grouped A/B path: one masked dispatch per distinct mode
+                for mid in distinct:
+                    mask = active & (modes == mid)
+                    self.st, so = self.engine.step_rows(
+                        self.st, MODE_NAMES[mid], mask)
+                    self.stats["steps"] += 1
+                    self._harvest(so, mask)
+        # decode wall time by tick class too (the step fetched its
+        # tokens, so the dispatch has drained)
+        add_span(self.stats, "sched.decode." + self._tick_class(modes, active),
+                 dec.seconds)
         return True
 
     @staticmethod

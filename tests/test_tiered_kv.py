@@ -381,16 +381,11 @@ def serve_tiered(tiny, small_spec, small_dcfg):
                         tier_lossless=True)
 
 
-class _FakeState:
-    def __init__(self, seq_len):
-        self.seq_len = np.asarray(seq_len, np.int32)
-
-
 def _bill(eng, mode, seq_len, rows):
     """Run _record_traffic against a fresh meter; return bytes billed."""
     saved, eng.traffic = eng.traffic, TrafficMeter()
     try:
-        eng._record_traffic(mode, _FakeState(seq_len), rows)
+        eng._record_traffic(mode, np.asarray(seq_len, np.int32), rows)
         return eng.traffic.bytes_by_mode.get(mode, 0)
     finally:
         eng.traffic = saved

@@ -6,7 +6,7 @@ tiles, VMEM overflow, unpartitionable calls.  These tests compile each
 kernel wrapper of ``repro.kernels.ops`` at Qwen3-8B widths (8 KV heads
 of 128, 32 query heads, 128-token blocks, bf16) for a described
 ``v5e:2x2`` topology — no chip needed — and assert the compiled program
-calls the Mosaic kernel (``tpu_custom_call``).
+calls the Mosaic kernel (``tpu_custom_call``), named after its call site.
 
 The topology is described inside a module-scoped fixture (never at
 import time): only one process may load the TPU library at a time, so
@@ -88,3 +88,22 @@ def test_kernel_compiles_for_v5e(name, one_chip, tpu_kernels):
     fn, args = _cases(one_chip)[name]
     compiled = jax.jit(fn).lower(*args).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+KERNEL_NAMES = {"block_summaries": "specpv_block_summary",
+                "retrieval_scores": "specpv_retrieval_score",
+                "paged_verify_attention": "specpv_attn_full",
+                "routed_partial_attention": "specpv_attn_partial",
+                "paged_prefill_attention": "specpv_attn_prefill"}
+
+
+@pytest.mark.parametrize("name", sorted(KERNEL_NAMES))
+def test_kernel_op_carries_its_name(name, one_chip, tpu_kernels):
+    """Every kernel op of the compiled wrapper is named after its call
+    site (``kernels/names.py``): the name a profiler trace shows."""
+    fn, args = _cases(one_chip)[name]
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    ops = [line.split(" = ")[0].strip() for line in text.splitlines()
+           if 'custom_call_target="tpu_custom_call"' in line]
+    assert ops and all(op.startswith(f"%{KERNEL_NAMES[name]}.")
+                       for op in ops), ops
