@@ -298,8 +298,9 @@ def phase_kernels(cfg, spec, dcfg, seed: int) -> None:
 
     q = normal(ks[0], (b, t, h, dh))
     qp = normal(ks[1], (b, tp, h, dh))
-    pool_k = normal(ks[2], (npages, bs, hk, dh))
-    pool_v = normal(ks[3], (npages, bs, hk, dh))
+    # two layers of the stored lane-dense pool; the kernels read layer 1
+    pool_k = normal(ks[2], (2, npages, bs, hk * dh))
+    pool_v = normal(ks[3], (2, npages, bs, hk * dh))
     rng = np.random.default_rng(seed)
     table = jnp.asarray(rng.permutation(np.arange(1, npages))
                         .reshape(b, nb).astype(np.int32))
@@ -321,16 +322,16 @@ def phase_kernels(cfg, spec, dcfg, seed: int) -> None:
             want = fn(*args, use_pallas=False)
         return got, want
 
-    got, want = both(ops.paged_verify_attention, q, pool_k, pool_v, table,
+    got, want = both(ops.paged_verify_attention, q, pool_k, pool_v, 1, table,
                      length)
     for name, g, w in zip(("max", "out"), attn(got), attn(want)):
         close(f"paged_verify_attention {name}", g, w)
-    got, want = both(ops.routed_partial_attention, q, pool_k, pool_v, sel,
+    got, want = both(ops.routed_partial_attention, q, pool_k, pool_v, 1, sel,
                      sel_len)
     for name, g, w in zip(("max", "out"), attn(got), attn(want)):
         close(f"routed_partial_attention {name}", g, w)
-    got, want = both(ops.paged_prefill_attention, qp, pool_k, pool_v, table,
-                     pf_len, pf_valid)
+    got, want = both(ops.paged_prefill_attention, qp, pool_k, pool_v, 1,
+                     table, pf_len, pf_valid)
     valid = np.arange(tp)[None, :] < np.asarray(pf_valid)[:, None]
     close("paged_prefill_attention", np.asarray(got, np.float32)[valid],
           np.asarray(want, np.float32)[valid])

@@ -413,7 +413,8 @@ class SpecPVEngine:
                 return cache, dcache
             wsc = jax.lax.with_sharding_constraint
             return (dict(cache, **{n: wsc(cache[n], self._pool_sharding(
-                        cache[n], 1)) for n in kvc.PAGED_POOL_KEYS}),
+                        cache[n], 1, lane_dense=n in ("k", "v")))
+                                   for n in kvc.PAGED_POOL_KEYS}),
                     dict(dcache, **{n: wsc(dcache[n], self._pool_sharding(
                         dcache[n], 0)) for n in kvc.DRAFT_POOL_KEYS}))
 
@@ -1255,8 +1256,8 @@ class SpecPVEngine:
         pool_spec = self._pool_sharding
         cache_sh = {}
         for k2, v in st.cache.items():
-            if k2 in kvc.PAGED_POOL_KEYS and self.paged:
-                cache_sh[k2] = pool_spec(v, 1)          # [L, NP, ...]
+            if k2 in kvc.PAGED_POOL_KEYS and self.paged:  # [L, NP, ...]
+                cache_sh[k2] = pool_spec(v, 1, lane_dense=k2 in ("k", "v"))
             elif k2 in ("k", "v", "kmax", "kmin"):      # [L, B, S|NB, ...]
                 sax = max_ if div(v.shape[2], self.model_shards) else None
                 cache_sh[k2] = ns(None, bax, sax, None, None)
@@ -1302,18 +1303,22 @@ class SpecPVEngine:
             keys=rowlike(st.keys), temps=rowlike(st.temps),
             pkv_blocks=rowlike(st.pkv_blocks))
 
-    def _pool_sharding(self, v, page_axis: int):
+    def _pool_sharding(self, v, page_axis: int, lane_dense: bool = False):
         """NamedSharding of a paged pool array (trunk ``[L, NP, ...]``
-        or draft ``[NP, ...]``, kv heads second to last): pages over
-        ``data`` (per-shard page ranges), kv heads over ``model`` —
-        tensor-parallel attention reads only its own heads, which is
-        how the kernels run under a mesh (``kernels.ops._per_shard``)."""
+        or draft ``[NP, ...]``): pages over ``data`` (per-shard page
+        ranges), kv heads over ``model`` — tensor-parallel attention
+        reads only its own heads, which is how the kernels run under a
+        mesh (``kernels.ops._per_shard``).  The heads are the second to
+        last axis, or with ``lane_dense`` (the trunk's K/V pool
+        ``[L, NP, block, Hk*Dh]``) the last one, split only into whole
+        heads."""
         from jax.sharding import NamedSharding, PartitionSpec as P
         spec = [None] * v.ndim
         if self.data_shards > 1 and v.shape[page_axis] % self.data_shards == 0:
             spec[page_axis] = "data"
-        if self.model_shards > 1 and v.shape[-2] % self.model_shards == 0:
-            spec[-2] = "model"
+        heads = self.cfg.num_kv_heads if lane_dense else v.shape[-2]
+        if self.model_shards > 1 and heads % self.model_shards == 0:
+            spec[-1 if lane_dense else -2] = "model"
         return NamedSharding(self.mesh, P(*spec))
 
     def _keep_placement(self, st: EngineState) -> EngineState:

@@ -33,7 +33,7 @@ from repro.configs.base import ModelConfig, SpecPVConfig
 from repro.core.tree import TreeSpec
 from repro.models.dense import (quest_block_scores, select_and_gather_partial,
                                 select_and_gather_partial_paged)
-from repro.kvcache.cache import (update_layer_summaries, paged_write_tokens,
+from repro.kvcache.cache import (update_layer_summaries, paged_write_layers,
                                  paged_update_summaries)
 
 
@@ -276,12 +276,8 @@ def _append_paged_cache(cache: Dict, ck, cv, count):
     blk = cache["k"].shape[2]
     new_len = length + count
     cache = dict(cache)
-    cache["k"] = jax.vmap(
-        lambda pool_l, new_l: paged_write_tokens(pool_l, pt, length, new_l)
-    )(cache["k"], ck)
-    cache["v"] = jax.vmap(
-        lambda pool_l, new_l: paged_write_tokens(pool_l, pt, length, new_l)
-    )(cache["v"], cv)
+    cache["k"] = paged_write_layers(cache["k"], pt, length, ck)
+    cache["v"] = paged_write_layers(cache["v"], pt, length, cv)
     n_touch = -(-w // blk) + 1
     nkmax, nkmin = jax.vmap(
         lambda kx, kn, pool_l: paged_update_summaries(
@@ -351,15 +347,19 @@ def refresh_partial_from_queries(cfg: ModelConfig, spec: SpecPVConfig,
     if paged:
         pt = cache["page_table"]
 
-        def per_layer(q_l, kmax_p, kmin_p, k_p, v_p):
+        def per_layer(q_l, kmax_p, kmin_p, layer):
             scores = _scores(q_l, kmax_p[pt], kmin_p[pt])
-            return select_and_gather_partial_paged(spec, scores, k_p, v_p,
-                                                   pt, cache["length"])
-    else:
-        def per_layer(q_l, kmax_l, kmin_l, k_l, v_l):
-            scores = _scores(q_l, kmax_l, kmin_l)
-            return select_and_gather_partial(spec, scores, k_l, v_l,
-                                             cache["length"])
+            return select_and_gather_partial_paged(
+                spec, scores, cache["k"], cache["v"], layer, pt,
+                cache["length"])
+        # the pool stacks are read in place at each layer's index
+        return jax.vmap(per_layer)(queries, cache["kmax"], cache["kmin"],
+                                   jnp.arange(queries.shape[0]))
+
+    def per_layer(q_l, kmax_l, kmin_l, k_l, v_l):
+        scores = _scores(q_l, kmax_l, kmin_l)
+        return select_and_gather_partial(spec, scores, k_l, v_l,
+                                         cache["length"])
     return jax.vmap(per_layer)(queries, cache["kmax"], cache["kmin"],
                                cache["k"], cache["v"])
 

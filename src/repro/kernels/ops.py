@@ -36,8 +36,10 @@ def _per_shard(fn, args, in_dims, out_dims, heads: int):
     XLA cannot partition a Mosaic kernel, so under a mesh the call goes
     through ``shard_map``: head-parallel over ``model`` when that axis
     divides the ``heads`` kv heads (``in_dims`` gives each operand's
-    head axis, None = replicated; ``out_dims`` each output's, in the
-    outputs' structure), otherwise every device computes the whole call.
+    head axis, None = replicated — a lane-dense pool's ``Hk*Dh`` axis,
+    whose ``model`` pieces are then whole heads; ``out_dims`` each
+    output's, in the outputs' structure), otherwise every device
+    computes the whole call.
     Without a mesh it is ``fn(*args)``."""
     from jax.sharding import PartitionSpec as P
     mesh = cm.current_mesh()
@@ -81,19 +83,21 @@ def retrieval_scores(q, kmax, kmin, q_weight, use_pallas: bool = True):
                     (2, 2, 2, None), 1, kmax.shape[2])
 
 
-def _block_attention(q, k_blocks, v_blocks, block_idx, block_valid_len,
+def _block_attention(q, k_pool, v_pool, layer, block_idx, block_valid_len,
                      q_pos=None, *, use_pallas: bool, name: str):
     """One row: the block-attention kernel named ``name`` (each call
     site its own, ``kernels/names.py``), or its ``ref`` oracle behind
-    the kernel's signature (``sparse_attention.block_attention_pallas``)."""
+    the kernel's signature (``sparse_attention.block_attention_pallas``:
+    a lane-dense pool stack ``[L, NB, bs, Hk*Dh]`` and a layer index)."""
     if use_pallas:
-        return block_attention_pallas(q, k_blocks, v_blocks, block_idx,
+        return block_attention_pallas(q, k_pool, v_pool, layer, block_idx,
                                       block_valid_len, q_pos,
                                       interpret=_interpret(), name=name)
-    nb, bs, hk, dh = k_blocks.shape
-    kf = k_blocks.reshape(nb * bs, hk, dh)
-    vf = v_blocks.reshape(nb * bs, hk, dh)
-    shape = (hk, block_idx.shape[1])
+    _, nb, bs, width = k_pool.shape
+    dh = q.shape[-1]
+    kf = k_pool[layer].reshape(nb * bs, width // dh, dh)
+    vf = v_pool[layer].reshape(nb * bs, width // dh, dh)
+    shape = (width // dh, block_idx.shape[1])
     idx = jnp.broadcast_to(block_idx, shape)
     vlen = jnp.broadcast_to(block_valid_len, shape)
     if q_pos is None:
@@ -103,24 +107,33 @@ def _block_attention(q, k_blocks, v_blocks, block_idx, block_valid_len,
                                            block_size=bs)
 
 
+def _kv_heads(pool, q):
+    """Kv heads of a lane-dense pool ``[..., Hk*Dh]`` read by ``q``
+    ``[..., H, Dh]``."""
+    return pool.shape[-1] // q.shape[-1]
+
+
 @functools.partial(jax.jit, static_argnames=("block_size", "use_pallas"))
 def sparse_verify_attention(q, k_cache, v_cache, block_idx, block_valid_len,
                             block_size: int = 128, use_pallas: bool = True):
     """Batched block-sparse verification attention partials.
 
     q: [B, T, H, Dh]; caches: [B, S, Hk, Dh]; idx/vlen: [B, Hk, NSel].
+    Each row's cache is viewed as a one-layer block pool
+    ``[1, S/block, block, Hk*Dh]``.
     Returns (m [B, H, T], l [B, H, T], acc [B, H, T, Dh])."""
     b, s, hk, dh = k_cache.shape
     nb = s // block_size
-    kb = k_cache[:, : nb * block_size].reshape(b, nb, block_size, hk, dh)
-    vb = v_cache[:, : nb * block_size].reshape(b, nb, block_size, hk, dh)
+    kb = k_cache[:, : nb * block_size].reshape(b, 1, nb, block_size, hk * dh)
+    vb = v_cache[:, : nb * block_size].reshape(b, 1, nb, block_size, hk * dh)
     fn = functools.partial(_block_attention, use_pallas=use_pallas,
                            name="specpv_attn_blocks")
-    return jax.vmap(fn)(q, kb, vb, block_idx, block_valid_len)
+    return jax.vmap(fn, in_axes=(0, 0, 0, None, 0, 0))(
+        q, kb, vb, 0, block_idx, block_valid_len)
 
 
 @functools.partial(jax.jit, static_argnames=("use_pallas",))
-def paged_verify_attention(q, pool_k, pool_v, page_table, length,
+def paged_verify_attention(q, pool_k, pool_v, layer, page_table, length,
                            use_pallas: bool = True):
     """Paged dense/refresh verification attention over the shared block
     pool (softmax partials).
@@ -139,14 +152,15 @@ def paged_verify_attention(q, pool_k, pool_v, page_table, length,
     entire rows collapse to re-reads of one resident page instead of
     pulling their whole table through the pipeline.
 
-    q: [B, T, H, Dh]; pool_k/pool_v: [NP, block, Hk, Dh] (one layer's
-    pool); page_table: [B, NB] int32; length: [B] — the fused step
+    q: [B, T, H, Dh]; pool_k/pool_v: [L, NP, block, Hk*Dh], the stored
+    lane-dense pool stack, read in place; layer: int32 scalar, the layer
+    to read; page_table: [B, NB] int32; length: [B] — the fused step
     passes a per-row *effective* length (0 for rows whose verification
     reads the partial cache instead).
     Returns (m [B, H, T], l [B, H, T], acc [B, H, T, Dh]) fp32 —
     combinable with the tree self-segment via
     ``models.common.combine_attn_parts``."""
-    bs = pool_k.shape[1]
+    bs = pool_k.shape[2]
     nb = page_table.shape[1]
     vlen = jnp.clip(length[:, None] - jnp.arange(nb)[None] * bs, 0, bs)
     # ragged routing: empty blocks stream the null page (their valid
@@ -154,16 +168,17 @@ def paged_verify_attention(q, pool_k, pool_v, page_table, length,
     routed = jnp.where(vlen > 0, page_table, 0)
     fn = jax.vmap(functools.partial(_block_attention, use_pallas=use_pallas,
                                     name="specpv_attn_full"),
-                  in_axes=(0, None, None, 0, 0))
-    return _per_shard(fn, (q, pool_k, pool_v,
+                  in_axes=(0, None, None, None, 0, 0))
+    return _per_shard(fn, (q, pool_k, pool_v, jnp.asarray(layer, jnp.int32),
                          routed[:, None].astype(jnp.int32),
                          vlen[:, None].astype(jnp.int32)),
-                    (2, 2, 2, None, None), (1, 1, 1), pool_k.shape[2])
+                    (2, 3, 3, None, None, None), (1, 1, 1),
+                    _kv_heads(pool_k, q))
 
 
 @functools.partial(jax.jit, static_argnames=("use_pallas",))
-def routed_partial_attention(q, pool_k, pool_v, block_idx, block_valid_len,
-                             use_pallas: bool = True):
+def routed_partial_attention(q, pool_k, pool_v, layer, block_idx,
+                             block_valid_len, use_pallas: bool = True):
     """Zero-copy partial verification attention: the retrieval-selected
     blocks are read *in place* from the shared block pool through a
     per-row, per-kv-head page list — no dense partial cache exists.
@@ -178,23 +193,25 @@ def routed_partial_attention(q, pool_k, pool_v, block_idx, block_valid_len,
     pool-write time, so retrieved blocks keep their true positions for
     free.
 
-    q: [B, T, H, Dh]; pool_k/pool_v: [NP, block, Hk, Dh] (one layer's
-    pool); block_idx: [B, Hk, NSel] routed physical page ids;
+    q: [B, T, H, Dh]; pool_k/pool_v: [L, NP, block, Hk*Dh], the stored
+    lane-dense pool stack; layer: int32 scalar, the layer to read;
+    block_idx: [B, Hk, NSel] routed physical page ids;
     block_valid_len: [B, Hk, NSel] valid tokens per selected block.
     Returns (m [B, H, T], l [B, H, T], acc [B, H, T, Dh]) fp32 —
     combinable with the tail-buffer and tree self-segments via
     ``models.common.merge_attn_partials``/``combine_attn_parts``."""
     fn = jax.vmap(functools.partial(_block_attention, use_pallas=use_pallas,
                                     name="specpv_attn_partial"),
-                  in_axes=(0, None, None, 0, 0))
-    return _per_shard(fn, (q, pool_k, pool_v, block_idx.astype(jnp.int32),
+                  in_axes=(0, None, None, None, 0, 0))
+    return _per_shard(fn, (q, pool_k, pool_v, jnp.asarray(layer, jnp.int32),
+                         block_idx.astype(jnp.int32),
                          block_valid_len.astype(jnp.int32)),
-                    (2, 2, 2, 1, 1), (1, 1, 1), pool_k.shape[2])
+                    (2, 3, 3, None, 1, 1), (1, 1, 1), _kv_heads(pool_k, q))
 
 
 @functools.partial(jax.jit, static_argnames=("use_pallas",))
-def paged_prefill_attention(q, pool_k, pool_v, page_table, length, t_valid,
-                            use_pallas: bool = True):
+def paged_prefill_attention(q, pool_k, pool_v, layer, page_table, length,
+                            t_valid, use_pallas: bool = True):
     """Blockwise-parallel paged prefill attention over the shared block
     pool — the batched-prefill counterpart of ``paged_verify_attention``.
 
@@ -210,14 +227,16 @@ def paged_prefill_attention(q, pool_k, pool_v, page_table, length, t_valid,
     masked.
 
     q: [B, T, H, Dh] (the tick's packed chunk queries);
-    pool_k/pool_v: [NP, block, Hk, Dh] (one layer's pool);
-    page_table: [B, NB] int32; length: [B] tokens already resident
+    pool_k/pool_v: [L, NP, block, Hk*Dh], a lane-dense pool stack (the
+    trunk passes the layer it just wrote as a one-layer stack); layer:
+    int32 scalar, the layer to read; page_table: [B, NB] int32;
+    length: [B] tokens already resident
     *before* this chunk; t_valid: [B] real (non-pad) chunk tokens per
     row — pad queries produce garbage rows the caller's feature masking
     discards.
     Returns normalised attention [B, T, H, Dh] in q's dtype (same
     contract as the flash fallback)."""
-    bs = pool_k.shape[1]
+    bs = pool_k.shape[2]
     t = q.shape[1]
     nb = page_table.shape[1]
     end = length + t_valid
@@ -226,11 +245,12 @@ def paged_prefill_attention(q, pool_k, pool_v, page_table, length, t_valid,
     q_pos = length.astype(jnp.int32)[:, None] + jnp.arange(t)[None]
     fn = jax.vmap(functools.partial(_block_attention, use_pallas=use_pallas,
                                     name="specpv_attn_prefill"),
-                  in_axes=(0, None, None, 0, 0, 0))
+                  in_axes=(0, None, None, None, 0, 0, 0))
     m, l, acc = _per_shard(fn, (q, pool_k, pool_v,
+                              jnp.asarray(layer, jnp.int32),
                               routed[:, None].astype(jnp.int32),
                               vlen[:, None].astype(jnp.int32), q_pos),
-                         (2, 2, 2, None, None, None), (1, 1, 1),
-                         pool_k.shape[2])
+                         (2, 3, 3, None, None, None, None), (1, 1, 1),
+                         _kv_heads(pool_k, q))
     out = acc / jnp.maximum(l, 1e-30)[..., None]           # [B, H, T, Dh]
     return out.transpose(0, 2, 1, 3).astype(q.dtype)

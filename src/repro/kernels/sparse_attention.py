@@ -10,11 +10,15 @@ VMEM scratch; the final grid step emits softmax partials that the caller
 merges with the small buffer/tree segment
 (``models.common.combine_attn_parts``).
 
-Layout.  The pool ``[NB, bs, Hk, Dh]`` is viewed as ``[NB, bs, Hk*Dh]``
-(a free reshape), and kv head ``h`` reads the ``(bs, Dh)`` tile at
-column block ``h`` — both tile dims are TPU-aligned.  A kv head's
-grouped queries are flattened to ``R = rep*T`` rows so each grid step is
-two plain 2-D MXU matmuls ``[R, Dh] x [Dh, bs]`` and ``[R, bs] x [bs, Dh]``.
+Layout.  The K/V operand is the stored page pool itself: a stack of
+per-layer pools ``[L, NB, bs, Hk*Dh]``, lane-dense (kv head ``h`` owns
+columns ``h*Dh:(h+1)*Dh``), plus the layer to read as a scalar-prefetch
+operand.  Grid step ``(h, j)`` streams the ``(bs, Dh)`` tile of page
+``block_idx[h, j]`` of that layer at column block ``h`` — both tile dims
+are TPU-aligned, and nothing copies the pool before the call.  A kv
+head's grouped queries are flattened to ``R = rep*T`` rows so each grid
+step is two plain 2-D MXU matmuls ``[R, Dh] x [Dh, bs]`` and
+``[R, bs] x [bs, Dh]``.
 
 Masking.  Key ``s`` of grid block ``j`` is valid while
 ``s < block_valid_len[h, j]``; with ``q_pos`` given (prefill) it must
@@ -40,7 +44,7 @@ from repro.kernels.names import named
 NEG = -1e30
 
 
-def _kernel(idx_ref, vlen_ref, *refs, block_size: int, nblk: int,
+def _kernel(layer_ref, idx_ref, vlen_ref, *refs, block_size: int, nblk: int,
             per_head: bool, causal: bool):
     if causal:
         qpos_ref, *refs = refs
@@ -55,8 +59,8 @@ def _kernel(idx_ref, vlen_ref, *refs, block_size: int, nblk: int,
         acc_s[...] = jnp.zeros_like(acc_s)
 
     q = q_ref[0].astype(jnp.float32)                      # [R, Dh]
-    k = k_ref[0].astype(jnp.float32)                      # [bs, Dh]
-    v = v_ref[0].astype(jnp.float32)                      # [bs, Dh]
+    k = k_ref[...].astype(jnp.float32)                    # [bs, Dh]
+    v = v_ref[...].astype(jnp.float32)                    # [bs, Dh]
     logits = jax.lax.dot_general(
         q, k, (((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32)               # [R, bs]
@@ -82,25 +86,29 @@ def _kernel(idx_ref, vlen_ref, *refs, block_size: int, nblk: int,
         acc_out[0] = acc_s[...]
 
 
-def block_attention_pallas(q, k_blocks, v_blocks, block_idx,
+def block_attention_pallas(q, k_pool, v_pool, layer, block_idx,
                            block_valid_len, q_pos: Optional[jax.Array] = None,
                            *, interpret: bool = True,
                            name: str = "specpv_attn_blocks"):
     """Softmax partials of ``q`` over routed KV blocks.
 
-    q: [T, H, Dh]; k_blocks/v_blocks: [NB, bs, Hk, Dh] (a page pool, or a
-    contiguous cache viewed as blocks); block_idx/block_valid_len:
-    [Hi, N] int32 with Hi = Hk (per-kv-head routing) or 1 (every head
-    reads the same blocks) — grid step j reads block ``block_idx[h, j]``
-    and keeps its first ``block_valid_len[h, j]`` keys; q_pos: [T] int32
-    absolute query positions, given for causal (prefill) attention where
-    grid step j is logical block j.  ``name`` names the kernel in the
-    compiled program and the profiler trace (``kernels/names.py``); under
-    ``vmap`` it runs once per row.
+    q: [T, H, Dh]; k_pool/v_pool: [L, NB, bs, Hk*Dh], a stack of page
+    pools stored lane-dense (the paged trunk pool, or a contiguous cache
+    viewed as one layer of blocks), read in place; layer: int32 scalar,
+    the stack entry to read; block_idx/block_valid_len: [Hi, N] int32
+    with Hi = Hk (per-kv-head routing) or 1 (every head reads the same
+    blocks) — grid step j reads block ``block_idx[h, j]`` and keeps its
+    first ``block_valid_len[h, j]`` keys; q_pos: [T] int32 absolute
+    query positions, given for causal (prefill) attention where grid
+    step j is logical block j.  ``Hk`` is the pool's width over q's
+    ``Dh``.  ``name`` names the kernel in the compiled program and the
+    profiler trace (``kernels/names.py``); under ``vmap`` it runs once
+    per row.
 
     Returns softmax partials (m [H, T], l [H, T], acc [H, T, Dh]) fp32."""
     t, h, dh = q.shape
-    nb, bs, hk, _ = k_blocks.shape
+    nl, nb, bs, width = k_pool.shape
+    hk = width // dh
     hi, nblk = block_idx.shape
     rep = h // hk
     r = rep * t
@@ -108,25 +116,22 @@ def block_attention_pallas(q, k_blocks, v_blocks, block_idx,
     scale = 1.0 / math.sqrt(dh)
     qg = (q.reshape(t, hk, rep, dh).transpose(1, 2, 0, 3)
           * scale).reshape(hk, r, dh)                      # [Hk, R, Dh]
-    with jax.named_scope("relayout"):      # a copy on TPU, not a view
-        kf = k_blocks.reshape(nb, bs, hk * dh)
-        vf = v_blocks.reshape(nb, bs, hk * dh)
 
-    def kv_map(hh, jj, idx, *_):
-        return idx[hh if hi > 1 else 0, jj], 0, hh
+    def kv_map(hh, jj, lyr, idx, *_):
+        return lyr[0], idx[hh if hi > 1 else 0, jj], 0, hh
 
     def head_map(hh, jj, *_):
         return hh, 0, 0
 
     in_specs = [pl.BlockSpec((1, r, dh), head_map),
-                pl.BlockSpec((1, bs, dh), kv_map),
-                pl.BlockSpec((1, bs, dh), kv_map)]
-    operands = [qg, kf, vf]
+                pl.BlockSpec((None, None, bs, dh), kv_map),
+                pl.BlockSpec((None, None, bs, dh), kv_map)]
+    operands = [qg, k_pool, v_pool]
     if causal:
         in_specs.insert(0, pl.BlockSpec((r, 1), lambda *_: (0, 0)))
         operands.insert(0, jnp.tile(q_pos.astype(jnp.int32), rep)[:, None])
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=3,
         grid=(hk, nblk),
         in_specs=in_specs,
         out_specs=[pl.BlockSpec((1, r, 1), head_map),
@@ -144,6 +149,7 @@ def block_attention_pallas(q, k_blocks, v_blocks, block_idx,
                           per_head=hi > 1, causal=causal),
         grid_spec=grid_spec, out_shape=out_shape, interpret=interpret),
         rows=True)
+    lyr = jnp.clip(jnp.asarray(layer, jnp.int32), 0, nl - 1).reshape(1)
     idx = jnp.clip(block_idx.astype(jnp.int32), 0, nb - 1)
-    m, l, acc = fn(idx, block_valid_len.astype(jnp.int32), *operands)
+    m, l, acc = fn(lyr, idx, block_valid_len.astype(jnp.int32), *operands)
     return (m.reshape(h, t), l.reshape(h, t), acc.reshape(h, t, dh))

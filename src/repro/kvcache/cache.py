@@ -12,10 +12,15 @@
 
 Paged variant (``page_table`` key present in the cache dict):
 
-* the full cache is a *shared block pool* ``k/v: [L, NumPages, block, Hk,
-  Dh]`` with per-slot page tables ``[B, S_max/block] int32`` mapping
+* the full cache is a *shared block pool* ``k/v: [L, NumPages, block,
+  Hk*Dh]`` with per-slot page tables ``[B, S_max/block] int32`` mapping
   logical blocks to physical pages, so resident memory scales with the
-  tokens actually held, not ``B x S_max``;
+  tokens actually held, not ``B x S_max``.  The pool is stored
+  lane-dense — kv head ``h`` owns columns ``h*Dh:(h+1)*Dh`` — because
+  that is the layout the Pallas attention kernels stream (``(block,
+  Dh)`` tiles, ``kernels/sparse_attention.py``): they read the stored
+  stack in place, and only the helpers below that touch a few pages
+  split the heads out;
 * summaries are keyed by *physical* page: ``kmax/kmin: [L, NumPages, Hk,
   Dh]``;
 * page 0 is the reserved null page — unallocated table entries point at
@@ -949,50 +954,107 @@ class PrefixCache:
 
 def init_paged_pool(num_layers: int, num_pages: int, block: int,
                     num_kv_heads: int, head_dim: int, dtype) -> dict:
-    """Shared pool + physical-page summaries (no page tables)."""
-    kv_shape = (num_layers, num_pages, block, num_kv_heads, head_dim)
+    """Shared lane-dense pool ``[L, NP, block, Hk*Dh]`` + physical-page
+    summaries ``[L, NP, Hk, Dh]`` (no page tables)."""
+    kv_shape = (num_layers, num_pages, block, num_kv_heads * head_dim)
     sm_shape = (num_layers, num_pages, num_kv_heads, head_dim)
     return {"k": jnp.zeros(kv_shape, dtype), "v": jnp.zeros(kv_shape, dtype),
             "kmax": jnp.zeros(sm_shape, jnp.float32),
             "kmin": jnp.zeros(sm_shape, jnp.float32)}
 
 
-def gather_page_view(pool_l: jax.Array, page_table: jax.Array) -> jax.Array:
+def gather_page_view(pool_l: jax.Array, page_table: jax.Array,
+                     head_dim: Optional[int] = None) -> jax.Array:
     """One layer's logical contiguous view through the page table.
 
-    pool_l: [NP, block, Hk, Dh]; page_table: [B, NB] ->
-    [B, NB*block, Hk, Dh].  Entries mapping to the null page read
-    whatever it holds — callers mask by position validity."""
+    pool_l: [NP, block, ...] (the trunk's lane-dense [NP, block, Hk*Dh]
+    or the draft's [NP, block, Hk, Dh]); page_table: [B, NB] ->
+    [B, NB*block, ...], with the trailing axis split into heads of
+    ``head_dim`` when given ([B, NB*block, Hk, Dh]).  Entries mapping to
+    the null page read whatever it holds — callers mask by position
+    validity."""
     b, nb = page_table.shape
     v = pool_l[page_table]                       # [B, NB, block, ...]
-    return v.reshape((b, nb * pool_l.shape[1]) + pool_l.shape[2:])
+    tail = pool_l.shape[2:] if head_dim is None else (-1, head_dim)
+    return v.reshape((b, nb * pool_l.shape[1]) + tail)
+
+
+def gather_head_blocks(pool: jax.Array, layer, pages: jax.Array,
+                       head_dim: int) -> jax.Array:
+    """Per-kv-head block tiles of a lane-dense pool stack, read in place.
+
+    pool: [L, NP, block, Hk*Dh]; layer: int32 scalar; pages: [B, Hk, NS]
+    physical page ids -> [B, Hk, NS, block, Dh], entry ``[b, h, s]``
+    the ``(block, Dh)`` tile of page ``pages[b, h, s]`` at kv head
+    ``h``'s columns — the tile the attention kernel streams, and
+    nothing more of the pool."""
+    b, hk, ns = pages.shape
+    blk = pool.shape[2]
+    col = jnp.broadcast_to(jnp.arange(hk, dtype=jnp.int32)[None, :, None]
+                           * head_dim, pages.shape)
+    lyr = jnp.asarray(layer, jnp.int32)
+
+    def tile(page, c):
+        return jax.lax.dynamic_slice(pool, (lyr, page, 0, c),
+                                     (1, 1, blk, head_dim))[0, 0]
+
+    out = jax.vmap(tile)(pages.reshape(-1).astype(jnp.int32),
+                         col.reshape(-1))
+    return out.reshape(b, hk, ns, blk, head_dim)
+
+
+def _token_rows(page_table: jax.Array, start, t: int, blk: int,
+                valid=None) -> jax.Array:
+    """[B*T] pool rows (``page * block + slot``) of ``t`` tokens written
+    at per-row logical offsets ``start`` through the table (clamping and
+    null-page routing as ``paged_write_tokens`` documents)."""
+    nb = page_table.shape[1]
+    idx = start[:, None] + jnp.arange(t)[None]               # [B, T] logical
+    idx = jnp.minimum(idx, nb * blk - 1)
+    pg = jnp.take_along_axis(page_table, idx // blk, axis=1)
+    if valid is not None:
+        pg = jnp.where(valid, pg, 0)
+    return (pg * blk + idx % blk).reshape(-1)
 
 
 def paged_write_tokens(pool_l: jax.Array, page_table: jax.Array, start,
                        new: jax.Array, valid=None) -> jax.Array:
     """Scatter `new` tokens at per-row logical offsets through the table.
 
-    pool_l: [NP, block, Hk, Dh]; page_table: [B, NB]; start: [B];
-    new: [B, T, Hk, Dh].  Positions beyond the table span are clamped
-    into the last logical block (an upstream admission error); positions
-    whose table entry is unallocated land in the null page and are never
-    read unmasked.  ``valid`` ([B, T] bool, optional) routes ragged pad
-    positions into the null page instead — fused multi-cursor prefill
-    packs rows of unequal chunk lengths and must not let a short row's
-    zero-padding clobber an allocated page."""
+    pool_l: [NP, block, ...]; page_table: [B, NB]; start: [B];
+    new: [B, T, Hk, Dh], stored in the pool's trailing layout (the
+    trunk's lane-dense ``Hk*Dh``).  Positions beyond the table span are
+    clamped into the last logical block (an upstream admission error);
+    positions whose table entry is unallocated land in the null page
+    and are never read unmasked.  ``valid`` ([B, T] bool, optional)
+    routes ragged pad positions into the null page instead — fused
+    multi-cursor prefill packs rows of unequal chunk lengths and must
+    not let a short row's zero-padding clobber an allocated page."""
     np_, blk = pool_l.shape[:2]
-    b, nb = page_table.shape
-    t = new.shape[1]
-    idx = start[:, None] + jnp.arange(t)[None]               # [B, T] logical
-    idx = jnp.minimum(idx, nb * blk - 1)
-    pg = jnp.take_along_axis(page_table, idx // blk, axis=1)
-    if valid is not None:
-        pg = jnp.where(valid, pg, 0)
-    flat = (pg * blk + idx % blk).reshape(-1)
+    b, t = new.shape[:2]
+    flat = _token_rows(page_table, start, t, blk, valid)
     pool_flat = pool_l.reshape((np_ * blk,) + pool_l.shape[2:])
     pool_flat = pool_flat.at[flat].set(
         new.astype(pool_l.dtype).reshape((b * t,) + pool_l.shape[2:]))
     return pool_flat.reshape(pool_l.shape)
+
+
+def paged_write_layers(pool: jax.Array, page_table: jax.Array, start,
+                       new: jax.Array) -> jax.Array:
+    """``paged_write_tokens`` into every layer of a pool stack at once.
+
+    pool: [L, NP, block, Hk*Dh]; new: [L, B, T, Hk, Dh].  One row
+    scatter over the stack seen as ``[L*NP*block, Hk*Dh]`` rows (a
+    bitcast): on TPU a per-layer ``vmap`` of the row scatter makes XLA
+    re-lay the whole stack out around it, twice per step."""
+    nl, np_, blk = pool.shape[:3]
+    b, t = new.shape[1:3]
+    flat = _token_rows(page_table, start, t, blk)
+    rows = (jnp.arange(nl)[:, None] * (np_ * blk) + flat[None]).reshape(-1)
+    width = pool.shape[3:]
+    out = pool.reshape((nl * np_ * blk,) + width).at[rows].set(
+        new.astype(pool.dtype).reshape((nl * b * t,) + width))
+    return out.reshape(pool.shape)
 
 
 def paged_update_summaries(kmax_p, kmin_p, pool_l, page_table, start, end,
@@ -1001,17 +1063,23 @@ def paged_update_summaries(kmax_p, kmin_p, pool_l, page_table, start, end,
     [start, end) of each row (paged counterpart of
     ``update_layer_summaries``; same masked max/min, keyed by page).
 
-    kmax_p/kmin_p: [NP, Hk, Dh]; pool_l: [NP, block, Hk, Dh];
+    kmax_p/kmin_p: [NP, Hk, Dh]; pool_l: [NP, block, Hk*Dh];
     page_table: [B, NB]; start/end: [B]; n_touch: static upper bound on
     touched blocks per row (cdiv(T, block) + 1)."""
-    np_, blk, hk, dh = pool_l.shape
+    blk = pool_l.shape[1]
+    hk, dh = kmax_p.shape[1:]
     b, nb = page_table.shape
     blk_lo = start // blk
     tb = blk_lo[:, None] + jnp.arange(n_touch)[None]         # [B, NT] logical
     in_range = (tb < (end[:, None] + blk - 1) // blk) & (tb < nb)
     tbc = jnp.minimum(tb, nb - 1)
     pg = jnp.take_along_axis(page_table, tbc, axis=1)        # [B, NT]
-    keys = pool_l[pg].astype(jnp.float32)                    # [B,NT,blk,Hk,Dh]
+    # whole pages as (1, block, Hk*Dh) slices: an index gather of the
+    # lane-dense pool, vmapped over layers, is re-laid out on TPU
+    keys = jax.vmap(lambda p: jax.lax.dynamic_slice_in_dim(pool_l, p, 1)[0])(
+        pg.reshape(-1))
+    keys = keys.astype(jnp.float32).reshape(
+        pg.shape + (blk, hk, dh))                            # [B,NT,blk,Hk,Dh]
     pos = tbc[:, :, None] * blk + jnp.arange(blk)[None, None]
     valid = (pos < end[:, None, None])[..., None, None]
     kmax_new = jnp.max(jnp.where(valid, keys, -1e30), axis=2)

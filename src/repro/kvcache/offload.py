@@ -106,8 +106,9 @@ class _HostSegment:
     """One demotion's worth of a slot's cold blocks, held host-side.
 
     ``k``/``v`` are int8 [L, n, block, Hk, Dh] with bf16 scales
-    [L, n, block, Hk] (or raw pool-dtype arrays and ``None`` scales when
-    lossless); ``kmax``/``kmin`` are the fp32 physical-page summaries
+    [L, n, block, Hk] (or the raw pool-layout [L, n, block, Hk*Dh]
+    arrays and ``None`` scales when lossless); ``kmax``/``kmin`` are the
+    fp32 physical-page summaries
     [L, n, Hk, Dh], saved so promotion restores retrieval scoring
     bit-for-bit."""
 
@@ -213,15 +214,17 @@ class TierManager:
         if not blocks:
             return cache
         pages = jnp.asarray([al.page_at(slot, j) for j in blocks], jnp.int32)
-        sub_k = cache["k"][:, pages]            # [L, n, block, Hk, Dh]
+        sub_k = cache["k"][:, pages]            # [L, n, block, Hk*Dh]
         sub_v = cache["v"][:, pages]
         if self.lossless:
             k, v = jax.device_get(sub_k), jax.device_get(sub_v)
             ks = vs = None
         else:
+            # per-(token, head) scales: split the lane-dense heads
+            heads = sub_k.shape[:3] + cache["kmax"].shape[2:]
             qfn = quantize_kv_fp8 if self.codec == "fp8" else quantize_kv
-            qk, sk = qfn(sub_k)
-            qv, sv = qfn(sub_v)
+            qk, sk = qfn(sub_k.reshape(heads))
+            qv, sv = qfn(sub_v.reshape(heads))
             k, ks = jax.device_get(qk), jax.device_get(sk)
             v, vs = jax.device_get(qv), jax.device_get(sv)
         seg = _HostSegment(blocks, k, v, ks, vs,
@@ -283,6 +286,8 @@ class TierManager:
                     dev[3], dev[4], dev[5]
                 k = dequantize_kv(qk, sk, dtype=pool_dtype)
                 v = dequantize_kv(qv, sv, dtype=pool_dtype)
+                k = k.reshape(k.shape[:3] + out["k"].shape[3:])
+                v = v.reshape(v.shape[:3] + out["v"].shape[3:])
             pages = jnp.asarray([self.alloc.promote(slot, j)
                                  for j in seg.blocks], jnp.int32)
             out["k"] = out["k"].at[:, pages].set(k.astype(pool_dtype))

@@ -74,9 +74,10 @@ def init_cache(cfg: ModelConfig, batch: int, max_len: int,
                num_pages: Optional[int] = None) -> Dict:
     """Cache dict.  ``paged=True`` (attention archs only) replaces the
     per-row [L, B, S_max, ...] layout with a shared block pool
-    [L, NumPages, block, ...] plus per-slot page tables — page 0 is the
-    reserved null page, so ``num_pages`` defaults to one more than the
-    contiguous capacity ``batch * S_max/block``."""
+    [L, NumPages, block, Hk*Dh] (lane-dense, ``kvcache/cache.py``) plus
+    per-slot page tables — page 0 is the reserved null page, so
+    ``num_pages`` defaults to one more than the contiguous capacity
+    ``batch * S_max/block``."""
     dtype = cm.dt(cfg.dtype)
     if cfg.arch_type == "ssm":
         assert not paged, "paged KV is attention-only"

@@ -262,26 +262,25 @@ def select_and_gather_partial(spec: SpecPVConfig, scores, k_layer, v_layer,
 
 
 def select_and_gather_partial_paged(spec: SpecPVConfig, scores, pool_k,
-                                    pool_v, page_table, length):
+                                    pool_v, layer, page_table, length):
     """Paged retrieval: translate the selected logical blocks through the
     page table and gather straight from the shared physical pool — the
     contiguous [B, S, ...] view is never materialised.
 
-    scores: [B, Hk, NB]; pool_k/pool_v: [NP, block, Hk, Dh];
-    page_table: [B, NB]; length: [B].  Same contract as
-    ``select_and_gather_partial``."""
-    np_, bs, hk, dh = pool_k.shape
-    b, nb = page_table.shape
+    scores: [B, Hk, NB]; pool_k/pool_v: [L, NP, block, Hk*Dh] (the
+    stored stack) and ``layer`` the one to read; page_table: [B, NB];
+    length: [B].  Same contract as ``select_and_gather_partial``."""
+    from repro.kvcache.cache import gather_head_blocks
+    bs = pool_k.shape[2]
+    b, hk, nb = scores.shape
+    dh = pool_k.shape[-1] // hk
     idx, slot_ok = _select_block_ids(spec, scores, length)
     ns = idx.shape[-1]
     idxc = jnp.minimum(idx, nb - 1)
     pg = jnp.take_along_axis(
         jnp.broadcast_to(page_table[:, None], (b, hk, nb)), idxc, axis=2)
-    pool_kh = jnp.moveaxis(pool_k, 2, 0)                  # [Hk, NP, bs, Dh]
-    pool_vh = jnp.moveaxis(pool_v, 2, 0)
-    hsel = jnp.arange(hk)[None, :, None]
-    pk = pool_kh[hsel, pg]                                # [B, Hk, NS, bs, Dh]
-    pv = pool_vh[hsel, pg]
+    pk = gather_head_blocks(pool_k, layer, pg, dh)        # [B, Hk, NS, bs, Dh]
+    pv = gather_head_blocks(pool_v, layer, pg, dh)
     # positions from the *unclamped* logical ids, matching the contiguous
     # gather: an out-of-table id yields pos >= length and masks itself
     pos = idx[..., None] * bs + jnp.arange(bs)[None, None, None]
@@ -303,13 +302,14 @@ def select_partial_blocks(spec: SpecPVConfig, scores, length):
     return jnp.where(slot_ok, idx, -1).astype(jnp.int32)
 
 
-def _routed_partial_context(q, pool_k, pool_v, page_table, pbi, length,
-                            pkv_l, use_kernel: bool):
+def _routed_partial_context(q, pool_k, pool_v, layer, page_table, pbi,
+                            length, pkv_l, use_kernel: bool):
     """Zero-copy partial context partials: the retrieval-selected blocks
-    are read *in place* from the layer's pool through the slot's live
-    page table (``pbi`` [B, Hk, NS] logical block ids, -1 = unused
-    selection slot), plus the small dense tail buffer that absorbs
-    between-refresh appended tokens as a second segment.
+    are read *in place* from layer ``layer`` of the pool stack
+    ([L, NP, block, Hk*Dh]) through the slot's live page table (``pbi``
+    [B, Hk, NS] logical block ids, -1 = unused selection slot), plus the
+    small dense tail buffer that absorbs between-refresh appended tokens
+    as a second segment.
 
     Off-kernel (CPU fallback) the two segments are CONCATENATED into
     one per-head dense partial in the gathered baseline's exact slot
@@ -320,9 +320,10 @@ def _routed_partial_context(q, pool_k, pool_v, page_table, pbi, length,
     ``kernels.ops.routed_partial_attention`` and merges the buffer
     partial with exp-rescaling (allclose; TPU or interpret-parity
     tests).  Returns (m, l, acc) fp32 partials."""
-    np_, bs, hk, dh = pool_k.shape
+    from repro.kvcache.cache import gather_head_blocks
+    bs, dh = pool_k.shape[2], q.shape[-1]
     b, nb = page_table.shape
-    ns = pbi.shape[-1]
+    hk, ns = pbi.shape[1:]
     pk_buf, pv_buf, ppos_buf = pkv_l[:3]
     idxc = jnp.clip(pbi, 0, nb - 1)
     pg = jnp.take_along_axis(
@@ -333,16 +334,13 @@ def _routed_partial_context(q, pool_k, pool_v, page_table, pbi, length,
             pbi >= 0,
             jnp.clip(length[:, None, None] - pbi * bs, 0, bs), 0)
         idx = jnp.where(pbi >= 0, pg, 0)
-        part_body = kops.routed_partial_attention(q, pool_k, pool_v,
+        part_body = kops.routed_partial_attention(q, pool_k, pool_v, layer,
                                                   idx, vlen)
         part_buf = cm.dense_attn_part_perhead(q, pk_buf, pv_buf,
                                               ppos_buf >= 0)
         return cm.merge_attn_partials([part_body, part_buf])
-    pool_kh = jnp.moveaxis(pool_k, 2, 0)                  # [Hk, NP, bs, Dh]
-    pool_vh = jnp.moveaxis(pool_v, 2, 0)
-    hsel = jnp.arange(hk)[None, :, None]
-    kb = pool_kh[hsel, pg].reshape(b, hk, ns * bs, dh)
-    vb = pool_vh[hsel, pg].reshape(b, hk, ns * bs, dh)
+    kb = gather_head_blocks(pool_k, layer, pg, dh).reshape(b, hk, ns * bs, dh)
+    vb = gather_head_blocks(pool_v, layer, pg, dh).reshape(b, hk, ns * bs, dh)
     pos = pbi[..., None] * bs + jnp.arange(bs)[None, None, None]
     valid = ((pbi >= 0)[..., None]
              & (pos < length[:, None, None, None])).reshape(b, hk, ns * bs)
@@ -368,13 +366,15 @@ def _self_attention(cfg: ModelConfig, mode: str,
                     lp: Dict, h, positions, self_mask, cache_kv, pkv,
                     length, inv_freq, mscale, page_table=None,
                     paged_kernel: bool = False, partial_rows=None,
-                    t_valid=None, pkv_blocks=None):
+                    t_valid=None, pkv_blocks=None, layer=None):
     """One self-attention sublayer under the given mode.
 
     cache_kv: (k_layer, v_layer) for prefill/decode_full/decode_fused
-              or None; with page_table set these are the layer's *pool*
-              slices [NP, block, Hk, Dh] read (and, for prefill,
-              written) through the table.  Tiered residency
+              or None; with page_table set these are read (and, for
+              prefill, written) through the table: prefill gets the
+              layer's *pool* slices [NP, block, Hk*Dh], the decode
+              modes the whole stored stacks [L, NP, block, Hk*Dh],
+              read in place at ``layer``.  Tiered residency
               (``kvcache.offload.TierManager``) never changes this
               contract: host-demoted pages are dequantized back into
               the fp pool *in pool dtype* before the step that reads
@@ -391,6 +391,8 @@ def _self_attention(cfg: ModelConfig, mode: str,
     partial_rows: [B] bool, decode_fused only — rows whose context is
               the materialised partial cache; all other rows attend the
               full cache over their real length.
+    layer:    int32 scalar, paged decode only — this layer's index in
+              the pool stacks of ``cache_kv``.
     pkv_blocks: [B, Hk, NS] int32 logical block ids (-1 = unused slot),
               zero-copy partial routing (paged caches only): the
               partial context is read in place from the pool through
@@ -433,7 +435,7 @@ def _self_attention(cfg: ModelConfig, mode: str,
         if page_table is not None:
             from repro.kvcache.cache import (paged_write_tokens,
                                              gather_page_view)
-            pool_k, pool_v = cache_kv[:2]     # [NP, block, Hk, Dh]
+            pool_k, pool_v = cache_kv[:2]     # [NP, block, Hk*Dh]
             pool_k = paged_write_tokens(pool_k, page_table, length, k_new,
                                         valid)
             pool_v = paged_write_tokens(pool_v, page_table, length, v_new,
@@ -449,10 +451,11 @@ def _self_attention(cfg: ModelConfig, mode: str,
                 tv = (t_valid if t_valid is not None
                       else jnp.full((b,), t, jnp.int32))
                 out = kops.paged_prefill_attention(
-                    q, pool_k, pool_v, page_table, length, tv)
+                    q, pool_k[None], pool_v[None], 0, page_table, length,
+                    tv)
                 return bk.attn_output(cfg, lp["attn"], out), upd, q
-            k_layer = gather_page_view(pool_k, page_table)
-            v_layer = gather_page_view(pool_v, page_table)
+            k_layer = gather_page_view(pool_k, page_table, q.shape[-1])
+            v_layer = gather_page_view(pool_v, page_table, q.shape[-1])
         else:
             k_layer, v_layer = cache_kv[:2]  # (int8 caches are decode-only)
             from repro.kvcache.cache import append_layer_kv
@@ -478,7 +481,7 @@ def _self_attention(cfg: ModelConfig, mode: str,
             # kernel; the contiguous [B, S, ...] view never materialises
             from repro.kernels import ops as kops
             part_ctx = kops.paged_verify_attention(
-                q, cache_kv[0], cache_kv[1], page_table, length)
+                q, cache_kv[0], cache_kv[1], layer, page_table, length)
             part_self = cm.dense_attn_part(q, k_new, v_new,
                                            mask=self_mask[:, None])
             out = cm.combine_attn_parts([part_ctx, part_self], h.dtype)
@@ -487,8 +490,10 @@ def _self_attention(cfg: ModelConfig, mode: str,
             return bk.attn_output(cfg, lp["attn"], out), upd, q
         if page_table is not None:
             from repro.kvcache.cache import gather_page_view
-            k_layer = gather_page_view(cache_kv[0], page_table)
-            v_layer = gather_page_view(cache_kv[1], page_table)
+            k_layer = gather_page_view(cache_kv[0][layer], page_table,
+                                       q.shape[-1])
+            v_layer = gather_page_view(cache_kv[1][layer], page_table,
+                                       q.shape[-1])
             ksc = vsc = None                  # int8 caches stay contiguous
         else:
             k_layer, v_layer = cache_kv[:2]
@@ -519,7 +524,7 @@ def _self_attention(cfg: ModelConfig, mode: str,
             assert page_table is not None and len(pkv) == 3, \
                 "zero-copy partial routing needs the paged fp cache"
             part_ctx = _routed_partial_context(
-                q, cache_kv[0], cache_kv[1], page_table, pkv_blocks,
+                q, cache_kv[0], cache_kv[1], layer, page_table, pkv_blocks,
                 length, pkv, paged_kernel)
         else:
             pk, pv, ppos = pkv[:3]
@@ -541,12 +546,14 @@ def _self_attention(cfg: ModelConfig, mode: str,
         if page_table is not None and paged_kernel:
             from repro.kernels import ops as kops
             part_full = kops.paged_verify_attention(
-                q, cache_kv[0], cache_kv[1], page_table, len_eff)
+                q, cache_kv[0], cache_kv[1], layer, page_table, len_eff)
         else:
             if page_table is not None:
                 from repro.kvcache.cache import gather_page_view
-                k_layer = gather_page_view(cache_kv[0], page_table)
-                v_layer = gather_page_view(cache_kv[1], page_table)
+                k_layer = gather_page_view(cache_kv[0][layer], page_table,
+                                           q.shape[-1])
+                v_layer = gather_page_view(cache_kv[1][layer], page_table,
+                                           q.shape[-1])
                 ksc = vsc = None
             else:
                 k_layer, v_layer = cache_kv[:2]
@@ -565,7 +572,7 @@ def _self_attention(cfg: ModelConfig, mode: str,
             assert page_table is not None and len(pkv) == 3, \
                 "zero-copy partial routing needs the paged fp cache"
             part_part = _routed_partial_context(
-                q, cache_kv[0], cache_kv[1], page_table, pkv_blocks,
+                q, cache_kv[0], cache_kv[1], layer, page_table, pkv_blocks,
                 length, pkv, paged_kernel)
         else:
             pk, pv, ppos = pkv[:3]
@@ -708,14 +715,20 @@ def trunk_fwd(cfg: ModelConfig, stack_params: Dict, h, positions, *,
     needs_cache = mode in ("prefill", "decode_full", "decode_fused") \
         or routed
     decode_mode = mode in ("decode_full", "decode_partial", "decode_fused")
+    # paged decode reads the stored pool stacks in place at each layer's
+    # index (the kernels' operand IS the stored buffer); scanning them as
+    # xs would copy one layer's pool per iteration.  Prefill writes its
+    # layer's pool and keeps the per-layer slice.
+    pool_stack = paged and decode_mode
 
     # ---- assemble scan xs --------------------------------------------------
     xs: Dict[str, Any] = {"slot_params": stack_params["slots"]}
     if needs_cache and n_attn_per:
         def rs(a):  # [L_attn, ...] -> [n_super, n_attn_per, ...]
             return a.reshape((n_super, n_attn_per) + a.shape[1:])
-        xs["ck"] = rs(cache["k"])
-        xs["cv"] = rs(cache["v"])
+        if not pool_stack:
+            xs["ck"] = rs(cache["k"])
+            xs["cv"] = rs(cache["v"])
         if "k_scale" in cache:   # int8 cache
             xs["cks"] = rs(cache["k_scale"])
             xs["cvs"] = rs(cache["v_scale"])
@@ -795,7 +808,11 @@ def trunk_fwd(cfg: ModelConfig, stack_params: Dict, h, positions, *,
         for j, kind in enumerate(pattern):
             lp = x["slot_params"][j]
             if kind in ("attn", "dec"):
-                if needs_cache:
+                layer = None
+                if needs_cache and pool_stack:
+                    cache_kv = (cache["k"], cache["v"])
+                    layer = x["sidx"] * n_attn_per + a_i
+                elif needs_cache:
                     cache_kv = (x["ck"][a_i], x["cv"][a_i])
                     if "cks" in x:
                         cache_kv += (x["cks"][a_i], x["cvs"][a_i])
@@ -812,7 +829,8 @@ def trunk_fwd(cfg: ModelConfig, stack_params: Dict, h, positions, *,
                     length, inv_freq, mscale, page_table=page_table,
                     paged_kernel=paged_kernel, partial_rows=partial_rows,
                     t_valid=t_valid,
-                    pkv_blocks=(x["pbi"][a_i] if "pbi" in x else None))
+                    pkv_blocks=(x["pbi"][a_i] if "pbi" in x else None),
+                    layer=layer)
                 h = h + att
                 if mode == "prefill":
                     if paged:
@@ -848,7 +866,7 @@ def trunk_fwd(cfg: ModelConfig, stack_params: Dict, h, positions, *,
                         score_mode=spec.score_mode, reduction=spec.reduction)
                     if paged:
                         ppk, ppv, pppos = select_and_gather_partial_paged(
-                            spec, scores, x["ck"][a_i], x["cv"][a_i],
+                            spec, scores, cache["k"], cache["v"], layer,
                             page_table, length)
                     else:
                         ppk, ppv, pppos = select_and_gather_partial(

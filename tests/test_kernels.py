@@ -114,17 +114,24 @@ def test_sparse_attention_equals_dense_when_all_selected():
     np.testing.assert_allclose(out_sparse, ref_out, rtol=2e-5, atol=2e-5)
 
 
+LAYERS = 3          # pool stack depth of the paged kernel tests
+
+
 @pytest.mark.prefill
 @pytest.mark.parametrize("shape", SHAPES)
 @pytest.mark.parametrize("dtype", DTYPES)
-def test_paged_prefill_attention_parity(shape, dtype):
-    """Pallas blockwise prefill kernel (interpret mode) vs the pure-jnp
-    oracle over shuffled page tables and ragged per-row chunk lengths."""
+@pytest.mark.parametrize("layer", [0, LAYERS - 1])
+def test_paged_prefill_attention_parity(shape, dtype, layer):
+    """Pallas blockwise prefill kernel (interpret mode) reading one layer
+    of a lane-dense pool stack vs the pure-jnp oracle over that layer
+    alone, over shuffled page tables and ragged per-row chunk lengths."""
     s, hk, dh, bs, h, t = shape
     b = 2
     npg = s // bs + 1
-    k = jax.random.normal(jax.random.PRNGKey(0), (npg, bs, hk, dh), dtype)
-    v = jax.random.normal(jax.random.PRNGKey(1), (npg, bs, hk, dh), dtype)
+    k = jax.random.normal(jax.random.PRNGKey(0),
+                          (LAYERS, npg, bs, hk * dh), dtype)
+    v = jax.random.normal(jax.random.PRNGKey(1),
+                          (LAYERS, npg, bs, hk * dh), dtype)
     q = jax.random.normal(jax.random.PRNGKey(2), (b, t, h, dh), dtype)
     nb = npg - 1
     rng = np.random.default_rng(3)
@@ -132,33 +139,34 @@ def test_paged_prefill_attention_parity(shape, dtype):
                                for _ in range(b)]), jnp.int32)
     length = jnp.asarray([bs + bs // 2, 0], jnp.int32)   # resumed + fresh
     t_valid = jnp.asarray([t, max(t - 2, 1)], jnp.int32)
-    a = ops.paged_prefill_attention(q, k, v, pt, length, t_valid,
+    a = ops.paged_prefill_attention(q, k, v, layer, pt, length, t_valid,
                                     use_pallas=True)
-    b_ = ops.paged_prefill_attention(q, k, v, pt, length, t_valid,
-                                     use_pallas=False)
+    b_ = ops.paged_prefill_attention(q, k[layer][None], v[layer][None], 0,
+                                     pt, length, t_valid, use_pallas=False)
     tol = 2e-5 if dtype == jnp.float32 else 3e-2
     np.testing.assert_allclose(np.asarray(a), np.asarray(b_), rtol=tol,
                                atol=tol)
 
 
 @pytest.mark.prefill
-def test_paged_prefill_attention_matches_flash():
+@pytest.mark.parametrize("layer", [0, LAYERS - 1])
+def test_paged_prefill_attention_matches_flash(layer):
     """The kernel's normalised output must match the flash fallback over
     the gathered logical view (same masking, absolute causal positions)."""
     from repro.models import common as cm
     b, hk, dh, bs, h, t, nb = 2, 2, 16, 16, 4, 12, 5
     npg = nb * b + 1
-    k = jax.random.normal(jax.random.PRNGKey(0), (npg, bs, hk, dh))
-    v = jax.random.normal(jax.random.PRNGKey(1), (npg, bs, hk, dh))
+    k = jax.random.normal(jax.random.PRNGKey(0), (LAYERS, npg, bs, hk * dh))
+    v = jax.random.normal(jax.random.PRNGKey(1), (LAYERS, npg, bs, hk * dh))
     q = jax.random.normal(jax.random.PRNGKey(2), (b, t, h, dh))
     pt = jnp.asarray([[1, 2, 3, 4, 5], [6, 7, 8, 9, 10]], jnp.int32)
     length = jnp.asarray([20, 0], jnp.int32)
     t_valid = jnp.asarray([t, 7], jnp.int32)
-    out = ops.paged_prefill_attention(q, k, v, pt, length, t_valid,
+    out = ops.paged_prefill_attention(q, k, v, layer, pt, length, t_valid,
                                       use_pallas=True)
-    kl = k.reshape(npg * bs, hk, dh)[
+    kl = k[layer].reshape(npg * bs, hk, dh)[
         (pt[:, :, None] * bs + jnp.arange(bs)[None, None]).reshape(b, -1)]
-    vl = v.reshape(npg * bs, hk, dh)[
+    vl = v[layer].reshape(npg * bs, hk, dh)[
         (pt[:, :, None] * bs + jnp.arange(bs)[None, None]).reshape(b, -1)]
     kv_pos = jnp.broadcast_to(jnp.arange(nb * bs)[None], (b, nb * bs))
     kv_valid = kv_pos < (length + t_valid)[:, None]

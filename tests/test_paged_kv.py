@@ -249,22 +249,27 @@ def test_paged_rejects_oversized_instead_of_corrupting(tiny, serve_paged):
 # ---------------------------------------------------------------------------
 
 @pytest.mark.parametrize("use_pallas", [True, False])
-def test_paged_verify_attention_matches_gathered_view(use_pallas):
+@pytest.mark.parametrize("layer", [0, 2])
+def test_paged_verify_attention_matches_gathered_view(use_pallas, layer):
+    """The kernel reads layer ``layer`` of the stored lane-dense pool
+    stack [L, NP, block, Hk*Dh] in place; the reference gathers that
+    layer's logical view."""
     from repro.kernels import ops as kops
     rng = np.random.default_rng(0)
     num_pages, bs, hk, dh, b, nb, t, h = 9, 16, 2, 8, 2, 4, 5, 4
-    pool_k = jnp.asarray(rng.normal(size=(num_pages, bs, hk, dh))
+    n_layers = 3
+    pool_k = jnp.asarray(rng.normal(size=(n_layers, num_pages, bs, hk * dh))
                          .astype(np.float32))
-    pool_v = jnp.asarray(rng.normal(size=(num_pages, bs, hk, dh))
+    pool_v = jnp.asarray(rng.normal(size=(n_layers, num_pages, bs, hk * dh))
                          .astype(np.float32))
     pt = jnp.asarray(np.array([[1, 3, 5, 0], [2, 4, 6, 7]], np.int32))
     length = jnp.asarray(np.array([41, 64], np.int32))
     q = jnp.asarray(rng.normal(size=(b, t, h, dh)).astype(np.float32))
 
-    m, l, acc = kops.paged_verify_attention(q, pool_k, pool_v, pt, length,
-                                            use_pallas=use_pallas)
-    kv_k = gather_page_view(pool_k, pt)
-    kv_v = gather_page_view(pool_v, pt)
+    m, l, acc = kops.paged_verify_attention(q, pool_k, pool_v, layer, pt,
+                                            length, use_pallas=use_pallas)
+    kv_k = gather_page_view(pool_k[layer], pt, dh)
+    kv_v = gather_page_view(pool_v[layer], pt, dh)
     valid = jnp.arange(nb * bs)[None] < length[:, None]
     mr, lr, accr = cm.dense_attn_part(q, kv_k, kv_v,
                                       mask=valid[:, None, None, :])

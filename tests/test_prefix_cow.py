@@ -260,24 +260,31 @@ def test_prefix_cache_eviction_skips_referenced_pages():
 # CoW data isolation (pool-level)
 # ---------------------------------------------------------------------------
 
-def test_cow_write_never_perturbs_other_holder():
+@pytest.mark.parametrize("layer", [0, 2])
+def test_cow_write_never_perturbs_other_holder(layer):
+    """On the stored lane-dense pool stack [L, NP, block, Hk*Dh]: a CoW
+    copy and a divergent write at every layer, read back at ``layer``."""
     rng = np.random.default_rng(3)
     al = PageAllocator(8)
-    blk, hk, dh = 4, 2, 8
-    pool = jnp.asarray(rng.normal(size=(8, blk, hk, dh)).astype(np.float32))
+    blk, hk, dh, n_layers = 4, 2, 8, 3
+    pool = jnp.asarray(rng.normal(size=(n_layers, 8, blk, hk * dh))
+                       .astype(np.float32))
     pages = al.alloc(0, 2)
     al.fork(0, 1)
     tables = np.stack([al.pages_of(0), al.pages_of(1)]).astype(np.int32)
-    view_before = np.asarray(gather_page_view(pool, jnp.asarray(tables))[0])
 
+    def view(slot):
+        return np.asarray(gather_page_view(pool[layer], jnp.asarray(tables),
+                                           dh)[slot])
+
+    view_before = view(0)
     # slot 1 CoWs block 1 and overwrites it
     old, new = al.cow_write(1, 1)
     assert new != old
     tables[1, 1] = new
-    pool = pool.at[new].set(pool[old])         # engine's device copy
-    pool = pool.at[new].set(-7.0)              # divergent write
-    view_a = np.asarray(gather_page_view(pool, jnp.asarray(tables))[0])
-    view_b = np.asarray(gather_page_view(pool, jnp.asarray(tables))[1])
+    pool = pool.at[:, new].set(pool[:, old])   # engine's device copy
+    pool = pool.at[:, new].set(-7.0)           # divergent write
+    view_a, view_b = view(0), view(1)
     assert np.array_equal(view_a, view_before)     # slot 0 untouched
     assert np.all(view_b[blk:] == -7.0)            # slot 1 sees its write
     assert al.refcount(pages[0]) == 2              # block 0 still shared

@@ -169,7 +169,7 @@ L, NP, BS, HK, DH = 2, 9, 4, 2, 4
 
 def _mk_pool(seed=0, dtype=np.float32):
     rng = np.random.default_rng(seed)
-    shape = (L, NP, BS, HK, DH)
+    shape = (L, NP, BS, HK * DH)            # the stored lane-dense pool
     cache = {
         "k": jnp.asarray(rng.normal(size=shape).astype(dtype)),
         "v": jnp.asarray(rng.normal(size=shape).astype(dtype)),

@@ -40,21 +40,25 @@ pytestmark = pytest.mark.zero_copy
 # kernel parity (quick-loop friendly)
 # ---------------------------------------------------------------------------
 
-def test_routed_attention_matches_ref_oracle(rng):
-    """Interpret-mode routed kernel vs the block-sparse reference, on a
-    random pool with ragged valid lengths and unused selection slots."""
+@pytest.mark.parametrize("layer", [0, 2])
+def test_routed_attention_matches_ref_oracle(rng, layer):
+    """Interpret-mode routed kernel vs the block-sparse reference, on
+    layer ``layer`` of a random lane-dense pool stack with ragged valid
+    lengths and unused selection slots."""
     from repro.kernels import ops, ref
-    b, t, h, hk, dh, npg, bs, ns = 2, 4, 4, 2, 16, 6, 16, 3
+    b, t, h, hk, dh, npg, bs, ns, nl = 2, 4, 4, 2, 16, 6, 16, 3, 3
     q = jnp.asarray(rng.normal(size=(b, t, h, dh)), jnp.float32)
-    pool_k = jnp.asarray(rng.normal(size=(npg, bs, hk, dh)), jnp.float32)
-    pool_v = jnp.asarray(rng.normal(size=(npg, bs, hk, dh)), jnp.float32)
+    pool_k = jnp.asarray(rng.normal(size=(nl, npg, bs, hk * dh)),
+                         jnp.float32)
+    pool_v = jnp.asarray(rng.normal(size=(nl, npg, bs, hk * dh)),
+                         jnp.float32)
     idx = jnp.asarray(rng.integers(0, npg, (b, hk, ns)), jnp.int32)
     vlen = jnp.asarray(rng.integers(0, bs + 1, (b, hk, ns)), jnp.int32)
-    m_k, l_k, acc_k = ops.routed_partial_attention(q, pool_k, pool_v,
+    m_k, l_k, acc_k = ops.routed_partial_attention(q, pool_k, pool_v, layer,
                                                    idx, vlen,
                                                    use_pallas=True)
-    k_flat = pool_k.reshape(npg * bs, hk, dh)
-    v_flat = pool_v.reshape(npg * bs, hk, dh)
+    k_flat = pool_k[layer].reshape(npg * bs, hk, dh)
+    v_flat = pool_v[layer].reshape(npg * bs, hk, dh)
     m_r, l_r, acc_r = jax.vmap(
         lambda qq, ii, vv: ref.sparse_verify_attention_ref(
             qq, k_flat, v_flat, ii, vv, block_size=bs),
