@@ -26,12 +26,12 @@ puts the reference computed in float8 in the program's place.
 """
 from __future__ import annotations
 
-import importlib.util
-import sys
 from pathlib import Path
 from typing import Dict, List, Sequence
 
 import numpy as np
+
+from model import load_module
 
 BENCH = Path(__file__).resolve().parent
 MIN_TOKENS = 320        # served tokens a sample holds at least
@@ -39,13 +39,9 @@ PAD = 1024              # reference sequences pad to whole PADs (compiles)
 EXACT = ("prefill", "full", "refresh")
 
 
-def reference(name: str):
-    spec = importlib.util.spec_from_file_location(
-        f"ref_{name}", BENCH / "refs" / f"{name}.py")
-    mod = importlib.util.module_from_spec(spec)
-    sys.modules[spec.name] = mod
-    spec.loader.exec_module(mod)
-    return mod
+def reference(name: str, root: Path = BENCH):
+    """The plain reference ``refs/<name>.py``."""
+    return load_module(root / "refs" / f"{name}.py", f"ref_{name}")
 
 
 def sample(finished: Sequence[dict], seed: int) -> List[dict]:
@@ -129,8 +125,8 @@ def served_logits(ref, dm, weights, spec: dict, req: dict,
     lg = np.array(ref.head(dm, weights, h[n0 - 1: n0 - 1 + m], fp8=fp8))
     for r in runs:
         rows = np.asarray([where[p] for p in r["queries"]])
-        sel = [ref.select(spec, ks[i], qs[i][rows], r["length"])
-               for i in range(dm.layers)]
+        sel = ref.select_layers(dm, spec, ks, [q[rows] for q in qs],
+                                r["length"])
         end = min(r["end"], n0 - 1 + m)
         hs = ref.segment(dm, weights, ks, vs, spec, sel, r["length"],
                          seq[r["start"]: end], r["start"], fp8=fp8)

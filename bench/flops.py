@@ -1,10 +1,12 @@
 """Operations and bytes the work needs, from shapes alone.
 
 These count what the mathematics requires — a matmul of [m, k] x [k, n]
-is 2mkn operations; attention of ``t`` queries over ``s`` keys is
-``4 t s H Dh`` (scores and the weighted sum of values) — and never what
+is 2mkn operations; in a layer of multi-head attention ``t`` queries
+over ``s`` keys take ``4 t s H Dh`` (scores and the weighted sum of
+values) — and never what
 a particular kernel happens to do, so that a kernel doing wasted work
-shows as a lower share of its roofline.
+shows as a lower share of its roofline.  The counts that depend on a
+model's layers come from its architecture module (``bench/archs/``).
 """
 from __future__ import annotations
 
@@ -12,8 +14,9 @@ import json
 from pathlib import Path
 from typing import Dict, Tuple
 
+from model import arch_of
+
 BENCH = Path(__file__).resolve().parent
-BF16 = 2
 
 
 def peaks(device_kind: str) -> Dict[str, float]:
@@ -34,29 +37,24 @@ def least_time(flops: float, nbytes: float, pk: Dict[str, float]
 
 
 def attn_flops(dm, t_q: int, s_kv: int) -> float:
-    """``t_q`` queries each attending ``s_kv`` keys, all heads."""
-    return 4.0 * t_q * s_kv * dm.heads * dm.head_dim
+    """``t_q`` queries each attending ``s_kv`` keys, summed over the
+    layers that attend (the architecture's count)."""
+    return arch_of(dm).attn_flops(dm, t_q, s_kv)
 
 
-def attn_bytes(dm, t_q: int, s_kv: int, itemsize: int = BF16) -> float:
-    """K and V of the attended keys (read once per kv head) plus the
-    queries read and the outputs written."""
-    kv = 2.0 * s_kv * dm.kv_heads * dm.head_dim * itemsize
-    qo = 2.0 * t_q * dm.heads * dm.head_dim * itemsize
-    return kv + qo
+def attn_bytes(dm, t_q: int, s_kv: int) -> float:
+    """K and V of the attended keys, the queries read and the outputs
+    written, summed over the layers that attend."""
+    return arch_of(dm).attn_bytes(dm, t_q, s_kv)
 
 
 def matmul_flops_per_token(dm, lm_head: bool = True) -> float:
-    """Projections and MLP of every layer (+ the LM head), per token."""
-    d = dm.d
-    per_layer = 2.0 * (d * dm.q_width + 2 * d * dm.kv_width
-                       + dm.q_width * d + 3 * d * dm.ff)
-    return dm.layers * per_layer + (2.0 * d * dm.vocab if lm_head else 0.0)
+    """Matrix products of every layer (+ the LM head), per token."""
+    return arch_of(dm).matmul_flops_per_token(dm, lm_head)
 
 
 def verify_flops(dm, t_q: int, attended: int) -> float:
     """Target-model operations of one verify row: ``t_q`` positions
     through every layer and the LM head, each attending ``attended``
     keys of context (the tree's own keys are not counted)."""
-    return (t_q * matmul_flops_per_token(dm)
-            + dm.layers * attn_flops(dm, t_q, attended))
+    return t_q * matmul_flops_per_token(dm) + attn_flops(dm, t_q, attended)

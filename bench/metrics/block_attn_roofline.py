@@ -21,7 +21,7 @@ def read(rec):
     for t in traced_ticks(rec):
         for _, mode, ctx, pend in t.rows:
             tq, kv = verify_shape(rec, mode, ctx, pend)
-            fl += dm.layers * flops.attn_flops(dm, tq, kv)
-            by += dm.layers * flops.attn_bytes(dm, tq, kv)
+            fl += flops.attn_flops(dm, tq, kv)
+            by += flops.attn_bytes(dm, tq, kv)
     least, _ = flops.least_time(fl, by, rec.peaks)
     return 100.0 * least / kernel_s

@@ -66,8 +66,8 @@ def attn_roofline(rec, kernel: str, modes) -> Optional[float]:
         for _, mode, ctx, pend in t.rows:
             if mode in modes:
                 tq, kv = verify_shape(rec, mode, ctx, pend)
-                fl += dm.layers * flops.attn_flops(dm, tq, kv)
-                by += dm.layers * flops.attn_bytes(dm, tq, kv)
+                fl += flops.attn_flops(dm, tq, kv)
+                by += flops.attn_bytes(dm, tq, kv)
     if fl <= 0:
         return None
     least, _ = flops.least_time(fl, by, rec.peaks)

@@ -249,6 +249,13 @@ def select(spec: dict, k, q, length: int) -> np.ndarray:
     return out
 
 
+def select_layers(dm, spec: dict, ks, qs, length: int) -> list:
+    """Blocks a refresh selects at every layer (``select`` on each):
+    ``ks[l]`` the layer's committed keys, ``qs[l]`` the refresh's
+    queries there."""
+    return [select(spec, k, q, length) for k, q in zip(ks, qs)]
+
+
 def segment(dm, weights, ks, vs, spec: dict, sel, length: int,
             tokens: np.ndarray, start: int, *, fp8: bool = False,
             rows: int = 128):

@@ -6,8 +6,18 @@ A cell is an entry of ``workloads`` in ``BENCHMARK.json``: a
 configuration (``bench/configs/<config>.json``) under a traffic mix
 (``bench/traffic/<traffic>.json``, whose ``generator`` names a module of
 ``bench/traffic/``), with the limits of its correctness numbers in
-``bench/limits/<cell>.json``.  Per-layer metrics are readers in
+``bench/limits/<cell>.json``.  The configuration's ``reference`` names
+its architecture: ``bench/archs/<reference>.py`` (sizes, the program's
+configuration, the weights' layout, work counts; the contract is in
+``bench/model.py``) and ``bench/refs/<reference>.py`` (the plain
+reference ``correct`` compares with).  Per-layer metrics are readers in
 ``bench/metrics/<metric>.py``.  Everything is found by name.
+
+So a new model comes as new files only, with no edit to a file that is
+there: ``configs/<config>.json``; ``archs/<name>.py`` and
+``refs/<name>.py`` where no architecture there fits it;
+``limits/<cell>.json`` for each cell; a traffic file or a reader where
+the cell needs one; and its entries in ``BENCHMARK.json``.
 
 Set-up: weights from the seed on the device, the engine, every decode
 and prefill variant of the cell compiled and run once, then the clients
@@ -31,7 +41,6 @@ T_START = time.perf_counter()
 
 import argparse                                          # noqa: E402
 import gc                                                # noqa: E402
-import importlib.util                                    # noqa: E402
 import json                                              # noqa: E402
 import os                                                # noqa: E402
 import shutil                                            # noqa: E402
@@ -45,6 +54,8 @@ ROOT = BENCH.parent
 sys.path.insert(0, str(BENCH))
 sys.path.insert(0, str(ROOT / "src"))
 
+from model import load_module                            # noqa: E402
+
 CACHE_DIR = ROOT / ".jax_cache"      # fixed, inside the checkout
 TRACE_SECONDS = 6.0                  # traced part of a --trace 1 window
 DRAIN_SECONDS = 60.0                 # wait for late first tokens / answers
@@ -52,14 +63,6 @@ DRAIN_SECONDS = 60.0                 # wait for late first tokens / answers
 
 def log(msg: str) -> None:
     print(msg, file=sys.stderr, flush=True)
-
-
-def load_module(path: Path, name: str):
-    spec = importlib.util.spec_from_file_location(name, path)
-    mod = importlib.util.module_from_spec(spec)
-    sys.modules[name] = mod         # dataclasses look their module up
-    spec.loader.exec_module(mod)
-    return mod
 
 
 def load_cell(name: str, root: Path = ROOT):
@@ -153,14 +156,16 @@ def run(args, *, root: Path = ROOT, chip_check: bool = True,
                          f"{cell['name']}.json").read_text())["limits"]
     genmod = load_module(root / "bench" / "traffic" /
                          f"{mix['generator']}.py", f"gen_{mix['generator']}")
-    dm = mdl.dims(conf)
+    arch = mdl.arch(conf, root / "bench")
+    dm = arch.dims(conf)
     gen = genmod.make(mix["params"], args.seed, dm.vocab)
     sv = conf["serving"]
 
     from weights import make_weights
     params, dparams = make_weights(dm, args.seed)
     jax.block_until_ready((params, dparams))
-    engine = serve.build_engine(conf, gen, params, dparams)
+    engine = serve.build_engine(arch.program_configs(conf), sv, gen, params,
+                                dparams)
     if tamper is not None:
         tamper(engine)
     warmed = serve.warm(engine, gen, sv["prefill_chunk"], sv["prefill_budget"])
@@ -283,7 +288,7 @@ def run(args, *, root: Path = ROOT, chip_check: bool = True,
         f"{failed} failed, {compiles} compiles in window; stats "
         f"{ {k: v for k, v in sorted(record.stats.items()) if v} }")
 
-    ref = cor.reference(conf["reference"])
+    ref = cor.reference(conf["reference"], root / "bench")
     reqs = cor.sample(finished, args.seed)
     t_ref = clock()
     ok, compared, vals = cor.check(ref, dm, params, conf["specpv"], reqs,

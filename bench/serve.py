@@ -34,15 +34,14 @@ def request_blocks(gen, spec, dcfg) -> int:
     return -(-need // spec.block_size)
 
 
-def build_engine(conf: dict, gen, params, dparams):
-    """The engine from the configuration file: ``max_len`` the largest
-    request rounded to whole blocks, and a page pool that holds every
-    client's largest request at once plus the null page, so admission
-    never stalls for pages."""
+def build_engine(configs, sv: dict, gen, params, dparams):
+    """The engine from the architecture's ``program_configs`` and the
+    configuration file's ``serving`` settings ``sv``: ``max_len`` the
+    largest request rounded to whole blocks, and a page pool that holds
+    every client's largest request at once plus the null page, so
+    admission never stalls for pages."""
     from repro.core.engine import SpecPVEngine
-    from model import program_configs
-    cfg, spec, dcfg = program_configs(conf)
-    sv = conf["serving"]
+    cfg, spec, dcfg = configs
     nb = request_blocks(gen, spec, dcfg)
     return SpecPVEngine(
         cfg, spec, dcfg, params, dparams, batch=gen.clients,

@@ -37,13 +37,15 @@ def programs():
 
     def run(q, pool_k, pool_v, table, length, sel, sel_len, kmax, kmin,
             qw):
-        full = ops.paged_verify_attention(q, pool_k, pool_v, table, length)
-        part = ops.routed_partial_attention(q, pool_k, pool_v, sel, sel_len)
+        full = ops.paged_verify_attention(q, pool_k, pool_v, 0, table,
+                                          length)
+        part = ops.routed_partial_attention(q, pool_k, pool_v, 0, sel,
+                                            sel_len)
         return full, part, ops.retrieval_scores(q, kmax, kmin, qw)
 
     def _prefill_chunk_fused(q, pool_k, pool_v, table, length, t_valid):
-        return ops.paged_prefill_attention(q, pool_k, pool_v, table, length,
-                                           t_valid)
+        return ops.paged_prefill_attention(q, pool_k, pool_v, 0, table,
+                                           length, t_valid)
 
     return (jax.jit(run), jax.jit(_prefill_chunk_fused),
             lambda k, n: ops.block_summaries(k, n, BS))
@@ -55,7 +57,9 @@ def operands():
     rng = np.random.default_rng(0)
     bf = jnp.bfloat16
     np_ = B * NB + 1
-    pool = jnp.asarray(rng.standard_normal((np_, BS, HK, DH)), bf)
+    # one layer of the stored lane-dense pool stack [L, NP, block, Hk*Dh]
+    pool = jnp.asarray(rng.standard_normal((np_, BS, HK, DH)), bf).reshape(
+        1, np_, BS, HK * DH)
     q = jnp.asarray(rng.standard_normal((B, T, H, DH)), bf)
     table = jnp.asarray(1 + np.arange(B * NB).reshape(B, NB), jnp.int32)
     length = jnp.asarray([NB * BS, NB * BS // 2], jnp.int32)

@@ -32,13 +32,13 @@ def main() -> None:
         return x @ x
 
     def attend(q, k, v, idx, vlen):
-        return block_attention_pallas(q, k, v, idx, vlen, interpret=False)
+        return block_attention_pallas(q, k, v, 0, idx, vlen, interpret=False)
 
     mm = jax.jit(matmul)
     at = jax.jit(attend)
     x = jnp.ones((2048, 2048), jnp.bfloat16)
     q = jnp.ones((61, 32, 128), jnp.bfloat16)
-    kv = jnp.ones((64, 128, 8, 128), jnp.bfloat16)
+    kv = jnp.ones((1, 64, 128, 8 * 128), jnp.bfloat16)    # [L, NP, bs, Hk*Dh]
     idx = jnp.arange(64, dtype=jnp.int32).reshape(8, 8) % 64
     vlen = jnp.full((8, 8), 128, jnp.int32)
     jax.block_until_ready((mm(x), at(q, kv, kv, idx, vlen)))

@@ -35,10 +35,12 @@ def test_parameter_counts(dm):
 
 
 def test_attention_counts_by_hand(dm):
-    # 61 queries over 20,000 keys: QK^T and PV, 2 flops a MAC each
-    assert flops.attn_flops(dm, 61, 20000) == 4 * 61 * 20000 * 32 * 128
+    # 61 queries over 20,000 keys: QK^T and PV, 2 flops a MAC each, in
+    # every layer (all of a dense model's layers attend)
+    assert flops.attn_flops(dm, 61, 20000) == \
+        dm.layers * 4 * 61 * 20000 * 32 * 128
     # K and V of 20,000 keys over 8 kv heads of 128, bf16, + q and out
-    assert flops.attn_bytes(dm, 61, 20000) == (
+    assert flops.attn_bytes(dm, 61, 20000) == dm.layers * (
         2 * 20000 * 8 * 128 * 2 + 2 * 61 * 32 * 128 * 2)
 
 

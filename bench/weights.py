@@ -1,10 +1,10 @@
 """Random weights from the seed, made on the device in one jitted call.
 
 The benchmark makes the weights itself, so the reference never reads
-anything the program made.  The pytree is laid out as the program's
-dense GQA model and EAGLE-3 draft take it: target ``{embed, final_norm,
-decoder: {slots: [layer stack], rem: []}, head}`` (no ``head`` when the
-embedding is tied) and draft ``{fuse, in_proj, layer, final_norm}``.
+anything the program made.  The pytree's layout and each leaf's
+shape and fan-in are the architecture's (``shapes`` in
+``bench/archs/<name>.py``); a leaf's key is the next of one split of
+the seed's key, in the tree's flattened order.
 
 Scales: embedding N(0, 0.02); every projection N(0, 1/fan_in); norm
 scales 1.  Everything is drawn in float32 and stored as bfloat16, the
@@ -16,7 +16,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from model import Dims
+from model import arch_of
 
 BF16 = jnp.bfloat16
 
@@ -27,44 +27,14 @@ def jax_seed(seed: int) -> int:
         0, 2**31 - 1))
 
 
-def _layer_shapes(dm: Dims, stack: tuple = ()):
-    d, f = dm.d, dm.ff
-    s = stack
-    return {
-        "norm1": (s + (d,), None),
-        "attn": {"wq": (s + (d, dm.q_width), d),
-                 "wk": (s + (d, dm.kv_width), d),
-                 "wv": (s + (d, dm.kv_width), d),
-                 "wo": (s + (dm.q_width, d), dm.q_width)},
-        "norm2": (s + (d,), None),
-        "mlp": {"wi": (s + (d, f), d), "wg": (s + (d, f), d),
-                "wo": (s + (f, d), f)},
-    }
-
-
-def shapes(dm: Dims):
-    """(target, draft) pytrees of (shape, fan_in); fan_in None = ones,
-    "embed" = N(0, 0.02)."""
-    target = {"embed": ((dm.vocab, dm.d), "embed"),
-              "final_norm": ((dm.d,), None),
-              "decoder": {"slots": [_layer_shapes(dm, (dm.layers,))],
-                          "rem": []}}
-    if not dm.tied:
-        target["head"] = ((dm.d, dm.vocab), dm.d)
-    draft = {"fuse": ((3 * dm.d, dm.d), 3 * dm.d),
-             "in_proj": ((2 * dm.d, dm.d), 2 * dm.d),
-             "layer": _layer_shapes(dm),
-             "final_norm": ((dm.d,), None)}
-    return target, draft
-
-
 def _is_leaf(x) -> bool:
     return isinstance(x, tuple) and len(x) == 2 and isinstance(x[0], tuple)
 
 
-def make_weights(dm: Dims, seed: int):
-    """(target, draft) weights on the default device, bf16."""
-    spec = shapes(dm)
+def make_weights(dm, seed: int):
+    """(target, draft) weights on the default device, bf16, in the layout
+    of ``dm``'s architecture (its ``shapes``)."""
+    spec = arch_of(dm).shapes(dm)
     leaves, tree = jax.tree_util.tree_flatten(spec, is_leaf=_is_leaf)
 
     def init(key):
