@@ -37,20 +37,33 @@ def init_attn_params(cfg: ModelConfig, key, *, cross: bool = False,
     return p
 
 
+def _head_norm(cfg: ModelConfig, x, scale):
+    """Qwen3's per-head RMSNorm over head_dim (``q_norm`` / ``k_norm``),
+    float32 inside, the activation dtype out."""
+    with jax.named_scope("qk_norm"):
+        return cm.rmsnorm(x, scale, cfg.norm_eps)
+
+
 def project_q(cfg: ModelConfig, p: Dict, x, positions, inv_freq, mscale):
-    """x: [B, T, d] -> roped q: [B, T, H, Dh]"""
+    """x: [B, T, d] -> roped q: [B, T, H, Dh].  When ``p`` holds
+    ``q_norm`` ([Dh], Qwen3), each head is RMS-normalised before RoPE."""
     b, t, _ = x.shape
     q = x @ p["wq"].astype(x.dtype)
     if "bq" in p:
         q = q + p["bq"].astype(x.dtype)
     q = q.reshape(b, t, cfg.num_heads, cfg.head_dim_)
+    if "q_norm" in p:
+        q = _head_norm(cfg, q, p["q_norm"])
     return cm.apply_rope(q, positions, inv_freq, mscale)
 
 
 def project_kv(cfg: ModelConfig, p: Dict, x, positions, inv_freq, mscale,
                *, rope: bool = True):
     """x: [B, T, d(or kv_in)] -> (k, v): [B, T, Hk, Dh]; k is roped so the KV
-    cache stores position-encoded keys (gatherable without re-roping)."""
+    cache stores position-encoded keys (gatherable without re-roping).
+    When ``p`` holds ``k_norm`` ([Dh], Qwen3), each key head is
+    RMS-normalised before RoPE, so the cache, and the block summaries
+    retrieval scores, hold normalised keys."""
     b, t, _ = x.shape
     k = x @ p["wk"].astype(x.dtype)
     v = x @ p["wv"].astype(x.dtype)
@@ -59,6 +72,8 @@ def project_kv(cfg: ModelConfig, p: Dict, x, positions, inv_freq, mscale,
         v = v + p["bv"].astype(x.dtype)
     k = k.reshape(b, t, cfg.num_kv_heads, cfg.head_dim_)
     v = v.reshape(b, t, cfg.num_kv_heads, cfg.head_dim_)
+    if "k_norm" in p:
+        k = _head_norm(cfg, k, p["k_norm"])
     if rope:
         k = cm.apply_rope(k, positions, inv_freq, mscale)
     return k, v
